@@ -1,8 +1,8 @@
 """Shared benchmark utilities.
 
-This module imports jax lazily: the fig benchmarks call
-``ensure_host_devices`` BEFORE the first jax import so that the
-shard_map engine can fake a P x Q device grid on CPU.
+This module imports jax lazily: the benchmarks call
+``force_host_devices`` / ``ensure_host_devices`` BEFORE the first jax
+import so that the mesh engines can fake a P x Q device grid on CPU.
 """
 from __future__ import annotations
 
@@ -19,24 +19,33 @@ OUT_DIR = os.environ.get(
                  "experiments", "bench"))
 
 
-def ensure_host_devices(argv, count: int = 32):
-    """Force ``count`` host devices when the argv selects the shard_map
-    engine.  Must run before anything imports jax (the device count is
-    locked at first init) -- call it between the stdlib imports and the
-    ``repro.*`` imports of a benchmark script."""
-    if not any("shard_map" in a or "async" in a or "overlap" in a
-               for a in argv):
-        return      # also matches the --engine=shard_map / =async forms
+def force_host_devices(count: int):
+    """Give the CPU backend ``count`` devices, so the mesh engines can lay
+    a P x Q grid out on one host.  Only on a CPU run
+    (``JAX_PLATFORMS=cpu``): on an accelerator the grid takes the real
+    devices.  Must run before jax initializes (the device count is
+    locked at first init)."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         return      # already forced (possibly by an earlier fig module)
     if "jax" in sys.modules:
-        print("warning: jax already initialized; --engine shard_map needs "
+        print("warning: jax already initialized; the mesh engines need "
               "XLA_FLAGS=--xla_force_host_platform_device_count=N set "
               "before the first jax import", file=sys.stderr)
         return
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={count}").strip()
+
+
+def ensure_host_devices(argv, count: int = 32):
+    """:func:`force_host_devices` when the argv selects a mesh engine --
+    call it between the stdlib imports and the ``repro.*`` imports of a
+    benchmark script."""
+    if any("shard_map" in a or "async" in a or "overlap" in a
+           for a in argv):
+        force_host_devices(count)  # also matches --engine=shard_map forms
 
 
 def add_engine_args(ap):

@@ -40,11 +40,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, os.path.join(ROOT, "src"))
 
-if "jax" not in sys.modules:
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
+try:
+    from .common import force_host_devices
+except ImportError:                       # run as a script
+    from common import force_host_devices
+
+force_host_devices(8)
 
 import numpy as np  # noqa: E402
 

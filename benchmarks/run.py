@@ -7,23 +7,25 @@ CSV rows.
     PYTHONPATH=src python -m benchmarks.run --engine shard_map --backend pallas
 
 The --engine / --backend pair is threaded through every fig benchmark via
-the unified solver API.  ``core`` (the engine x backend throughput grid)
-always runs in a subprocess: it forces a fake 8-device host platform,
-which must happen before jax initializes.
+the unified solver API.  Everything runs in this one process: a device
+belongs to one process at a time, so a child started after this process
+touched jax could not reach the chip.  On a CPU run the host platform is
+given 32 devices before jax initializes -- ``core`` and ``compress`` lay
+their mesh engines over 8, a fig benchmark's ``--engine shard_map`` grid
+over up to 32.
 """
 from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))), "src"))
 
-from .common import ensure_host_devices  # noqa: E402
+from .common import force_host_devices  # noqa: E402
 
-ensure_host_devices(sys.argv)
+force_host_devices(32)
 
 
 def main(argv=None) -> None:
@@ -63,24 +65,12 @@ def main(argv=None) -> None:
         fig6_weak.main(["--scale", "0.005" if args.quick else "0.01",
                         "--iters", "6" if args.quick else "12",
                         "--max-p", "3" if args.quick else "4"] + eb)
-    # these force their own host device count, which only takes effect
-    # before jax initializes -> subprocess
-    for bench, module in (("core", "benchmarks.core_bench"),
-                          ("compress", "benchmarks.fig_compress")):
-        if not want(bench):
-            continue
-        cmd = [sys.executable, "-m", module]
-        if args.quick:
-            cmd.append("--quick")
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))), "src"))
-        r = subprocess.run(cmd, env=env, cwd=os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        if r.returncode:
-            # fail the harness like every other benchmark would
-            print(f"{bench},0.0,failed(rc={r.returncode})")
-            raise SystemExit(r.returncode)
+    if want("core"):
+        from . import core_bench
+        core_bench.main(["--quick"] if args.quick else [])
+    if want("compress"):
+        from . import fig_compress
+        fig_compress.main(["--quick"] if args.quick else [])
     if want("kernels"):
         from . import kernels_bench
         kernels_bench.main([])

@@ -8,10 +8,10 @@ Both take a ``backend`` knob ("ref" | "pallas"):
 
   * ``backend="ref"`` runs the pure-jnp lax.scan implementation below;
   * ``backend="pallas"`` dispatches to the Pallas TPU kernels in
-    ``repro.kernels.sdca`` / ``repro.kernels.svrg`` (interpret mode on
-    CPU, real kernels on TPU).  The coordinate order is drawn from the
-    same PRNG key either way, so the two backends agree to float
-    tolerance.  The kernels support hinge and squared losses; logistic
+    ``repro.kernels.sdca`` / ``repro.kernels.svrg`` (compiled on a TPU,
+    the Pallas interpreter elsewhere).  The coordinate order is drawn
+    from the same PRNG key either way, so the two backends agree to
+    float tolerance.  The kernels support hinge and squared losses; logistic
     raises (use backend="ref").
 
 The knob is threaded end-to-end from the solver API
@@ -33,11 +33,6 @@ def _check_pallas_loss(loss: Loss):
         raise NotImplementedError(
             f"local_backend='pallas' supports losses {PALLAS_LOSSES}, not "
             f"{loss.name!r}; use local_backend='ref' for {loss.name}")
-
-
-def _interpret() -> bool:
-    from repro.kernels import default_interpret
-    return default_interpret()
 
 
 # ----------------------------------------------------------------------------
@@ -63,7 +58,7 @@ def local_sdca(loss: Loss, x, y, mask, alpha0, w0, *, lam, n, Q,
         paper's per-partition sampling).
       step_mode: "exact" uses ||x_i||^2; "beta" uses the paper's step-size
         parameter ``beta`` (they use beta = lam / t).
-      backend: "ref" (pure jnp) | "pallas" (TPU kernel; interpret on CPU).
+      backend: "ref" (pure jnp) | "pallas" (TPU kernel).
 
     Returns:
       delta_alpha: (n_p,) accumulated dual change of this cell.
@@ -77,7 +72,7 @@ def local_sdca(loss: Loss, x, y, mask, alpha0, w0, *, lam, n, Q,
         from repro.kernels.sdca import sdca_epoch_pallas
         dalpha, _ = sdca_epoch_pallas(
             x, y, mask, alpha0, w0, idx, lam=lam, n=n, Q=Q, loss=loss.name,
-            beta=(beta if use_beta else None), interpret=_interpret())
+            beta=(beta if use_beta else None))
         return dalpha
     if backend != "ref":
         raise ValueError(f"unknown local backend {backend!r}")
@@ -128,7 +123,7 @@ def local_svrg(loss: Loss, x_sub, y, mask, z_anchor, w_anchor_sub, mu_sub,
       mu_sub: (m_sub,) coordinates of the full anchor gradient of F
         (includes the 2*lam*w_tilde term).
       eta: learning rate eta_t.
-      backend: "ref" (pure jnp) | "pallas" (TPU kernel; interpret on CPU).
+      backend: "ref" (pure jnp) | "pallas" (TPU kernel).
 
     Returns:
       w_sub: (m_sub,) updated sub-block.
@@ -143,15 +138,15 @@ def local_svrg(loss: Loss, x_sub, y, mask, z_anchor, w_anchor_sub, mu_sub,
         if lo is None:
             x_k = x_sub
         else:
-            # The kernel gathers one (1, m_sub) row per step straight out
-            # of this slice via scalar-prefetched DMA, so the fused
+            # The kernel DMAs the (8, m_sub) tile holding each sampled
+            # row straight out of this slice (scalar prefetch), so the fused
             # column-slice pathology of the jnp path does not apply: the
             # slice is materialized once per outer iteration, not once
             # per inner step.
             x_k = jax.lax.dynamic_slice(x_sub, (0, lo), (n_p, m_sub))
         return svrg_inner_pallas(x_k, y, mask, z_anchor, w_anchor_sub,
                                  mu_sub, idx, lam=lam, eta=eta,
-                                 loss=loss.name, interpret=_interpret())
+                                 loss=loss.name)
     if backend != "ref":
         raise ValueError(f"unknown local backend {backend!r}")
 
@@ -205,8 +200,7 @@ def local_sdca_sparse(loss: Loss, cols, vals, y, mask, alpha0, w0, *, lam, n,
         from repro.kernels.sdca import sdca_epoch_sparse_pallas
         dalpha, _ = sdca_epoch_sparse_pallas(
             cols, vals, y, mask, alpha0, w0, idx, lam=lam, n=n, Q=Q,
-            loss=loss.name, beta=(beta if use_beta else None),
-            interpret=_interpret())
+            loss=loss.name, beta=(beta if use_beta else None))
         return dalpha
     if backend != "ref":
         raise ValueError(f"unknown local backend {backend!r}")
@@ -254,7 +248,7 @@ def local_svrg_sparse(loss: Loss, cols, vals, y, mask, z_anchor,
         from repro.kernels.svrg import svrg_inner_sparse_pallas
         return svrg_inner_sparse_pallas(
             cols, vals, y, mask, z_anchor, w_anchor_sub, mu_sub, idx,
-            lam=lam, eta=eta, lo=lo, loss=loss.name, interpret=_interpret())
+            lam=lam, eta=eta, lo=lo, loss=loss.name)
     if backend != "ref":
         raise ValueError(f"unknown local backend {backend!r}")
 

@@ -7,39 +7,20 @@ import jax
 def pvary(x, axes):
     """Mark ``x`` as varying over the given manual mesh axes.
 
-    shard_map in recent JAX tracks which mesh axes each value varies over;
-    inputs that are replicated along an axis must be explicitly promoted
-    before being mixed with values that vary along it inside lax control
-    flow.  Uses ``jax.lax.pcast`` (new name) with ``pvary`` fallback; on
-    older JAX (no varying-manual-axes tracking, shard_map runs with
-    replication checking off) it is the identity.
+    shard_map tracks which mesh axes each value varies over; inputs
+    that are replicated along an axis must be explicitly promoted before
+    being mixed with values that vary along it inside lax control flow.
     """
     axes = tuple(axes)
     if not axes:
         return x
-    if hasattr(jax.lax, "pcast"):
-        try:
-            return jax.lax.pcast(x, axes, to="varying")
-        except TypeError:
-            pass
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with vma checking off; falls back to
-    ``jax.experimental.shard_map`` (check_rep=False) on jax <= 0.4.x."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """``jax.shard_map`` with vma checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def as_axes(axis) -> tuple:

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -68,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_vmem, l_vmem, acc_vmem,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
-                           block_q=512, block_k=512, interpret=True):
+                           block_q=512, block_k=512, interpret=None):
     """q,k,v: (BH, S, D) with kv pre-expanded to H heads. Returns (BH,S,D)."""
     BH, S, D = q.shape
     Skv = k.shape[1]
@@ -93,5 +95,5 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

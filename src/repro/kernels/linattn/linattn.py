@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, logw_ref, u_ref, o_ref, state_ref,
             s_vmem, *, C, D, nc):
@@ -70,7 +72,7 @@ def _kernel(r_ref, k_ref, v_ref, logw_ref, u_ref, o_ref, state_ref,
         state_ref[0] = s_vmem[...]
 
 
-def rwkv_linattn_pallas(r, k, v, logw, u, *, chunk=64, interpret=True):
+def rwkv_linattn_pallas(r, k, v, logw, u, *, chunk=64, interpret=None):
     """r,k,v,logw: (BH, S, D); u: (D,). Returns (out, final_state)."""
     BH, S, D = r.shape
     C = min(chunk, S)
@@ -96,6 +98,6 @@ def rwkv_linattn_pallas(r, k, v, logw, u, *, chunk=64, interpret=True):
             jax.ShapeDtypeStruct((BH, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, logw, u[None, :])
     return out, state

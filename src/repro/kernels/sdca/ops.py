@@ -5,7 +5,6 @@ from functools import partial
 
 import jax
 
-from .. import default_interpret
 from .ref import sdca_epoch_ref
 from .sdca import sdca_epoch_pallas
 
@@ -16,15 +15,14 @@ def sdca_epoch(x, y, mask, alpha0, w0, idx, *, lam, n, Q, loss="hinge",
                backend="pallas", beta=None, interpret=None):
     """One local SDCA epoch on a data block.
 
-    backend="pallas": TPU kernel (interpret-mode on CPU).
+    backend="pallas": TPU kernel (``interpret=None`` follows
+    ``repro.kernels.default_interpret``).
     backend="ref": pure-jnp oracle.
     ``beta`` (runtime scalar or None) selects step_mode="beta".
     """
     if backend == "ref":
         return sdca_epoch_ref(x, y, mask, alpha0, w0, idx,
                               lam=lam, n=n, Q=Q, loss=loss, beta=beta)
-    if interpret is None:
-        interpret = default_interpret()
     return sdca_epoch_pallas(x, y, mask, alpha0, w0, idx,
                              lam=lam, n=n, Q=Q, loss=loss, beta=beta,
                              interpret=interpret)

@@ -4,13 +4,15 @@ TPU adaptation of the paper's random-access CPU loop (DESIGN.md §2):
 
   * the random coordinate order is materialized ONCE per epoch on the host
     and fed through scalar prefetch (``PrefetchScalarGridSpec``) -- the
-    row DMA for step h+1 is issued while step h computes (Pallas
-    double-buffers the gathered row blocks);
+    DMA of the (8, m_q) tile holding row idx[h+1] is issued while step h
+    computes (Pallas double-buffers the tiles); the row itself is sliced
+    out of its tile inside the kernel (``repro.kernels.lanes``);
   * the grid is the step counter (TPU grids execute sequentially, which
     is exactly the dependency structure of dual coordinate ascent);
-  * the running primal block w and the dual deltas live in VMEM scratch
-    for the whole epoch; nothing but one data row moves per step;
-  * outputs are flushed on the last step;
+  * labels, mask, alpha0 and the dual deltas are lane-dense (n_p / 128,
+    128) blocks resident in VMEM for the whole epoch, and the running
+    primal block w lives in its resident output block; nothing but one
+    data tile moves per step;
   * the paper's beta step-size variant (step_mode="beta", beta = lam/t)
     rides along as a second scalar-prefetch argument -- beta changes every
     outer iteration, so it must be a runtime input, not a compile-time
@@ -24,126 +26,117 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _static_scalar(v) -> bool:
-    """True when ``v`` can be baked into the kernel as a compile-time
-    constant (a plain host scalar, not a traced value)."""
-    return isinstance(v, (int, float, np.integer, np.floating))
+from .. import resolve_interpret
+from ..lanes import (add_lane, from_lanes, read_lane, row_tile, static_scalar,
+                     to_lanes)
 
 
-def _kernel(idx_ref,            # scalar prefetch: (steps,) int32
-            params_ref,         # scalar prefetch: (3,) f32 [beta, lam, n]
-            x_row_ref,          # (1, m_q) gathered row
-            y_row_ref,          # (1, 1) label
-            mask_row_ref,       # (1, 1)
-            alpha_row_ref,      # (1, 1) alpha0[i]
-            w0_ref,             # (1, m_q) initial w block
-            dalpha_ref,         # out: (n_p, 1)
-            w_out_ref,          # out: (1, m_q)
-            w_vmem,             # scratch: (1, m_q) f32
-            dal_vmem,           # scratch: (n_p, 1) f32
-            *, lam, n, Q, steps, loss, use_beta, runtime):
-    h = pl.program_id(0)
-
-    @pl.when(h == 0)
-    def _init():
-        w_vmem[...] = w0_ref[...].astype(jnp.float32)
-        dal_vmem[...] = jnp.zeros_like(dal_vmem)
-
-    i = idx_ref[h]
-    xi = x_row_ref[0, :].astype(jnp.float32)
-    yi = y_row_ref[0, 0].astype(jnp.float32)
-    mi = mask_row_ref[0, 0].astype(jnp.float32)
-    a_i = alpha_row_ref[0, 0].astype(jnp.float32) + dal_vmem[i, 0]
-    # runtime mode (the fleet path): lam / n arrive as traced scalars in
-    # the prefetch params vector; static mode bakes the Python constants
-    # so the compiled kernel is unchanged
-    lam_v = params_ref[1] if runtime else lam
-    n_v = params_ref[2] if runtime else n
-
-    w = w_vmem[0, :]
-    zloc = jnp.sum(xi * w)
-    x_sq = jnp.sum(xi * xi)
-    denom = params_ref[0] if use_beta else x_sq
+def sdca_delta(loss, a_i, zloc, yi, denom, lam_v, n_v, Q):
+    """Closed-form dual step of one coordinate; shared by the dense and
+    sparse kernels.  All array arguments are (1, 1) values."""
     denom = jnp.maximum(denom, 1e-12)
-
     if loss == "hinge":
         d = (yi / Q - zloc) * lam_v * n_v / denom
         lo = jnp.where(yi > 0, 0.0, -1.0)
         hi = jnp.where(yi > 0, 1.0, 0.0)
-        d = jnp.clip(a_i + d, lo, hi) - a_i
-    elif loss == "squared":
+        return jnp.clip(a_i + d, lo, hi) - a_i
+    if loss == "squared":
         num = yi / Q - a_i / (2.0 * Q) - zloc
         den = 1.0 / (2.0 * Q) + denom / (lam_v * n_v)
-        d = num / jnp.maximum(den, 1e-12)
-    else:
-        raise ValueError(loss)
-    d = d * mi
+        return num / jnp.maximum(den, 1e-12)
+    raise ValueError(loss)
 
-    w_vmem[0, :] = w + (d / (lam_v * n_v)) * xi
-    dal_vmem[i, 0] = dal_vmem[i, 0] + d
 
-    @pl.when(h == steps - 1)
-    def _flush():
-        dalpha_ref[...] = dal_vmem[...]
-        w_out_ref[...] = w_vmem[...]
+def _kernel(idx_ref,            # scalar prefetch: (steps,) int32
+            params_ref,         # scalar prefetch: (3,) f32 [beta, lam, n]
+            x_ref,              # (tr, m_q) tile holding row idx[h]
+            y_ref,              # (n_p / 128, 128) labels
+            mask_ref,           # (n_p / 128, 128)
+            alpha_ref,          # (n_p / 128, 128) alpha0
+            w0_ref,             # (1, m_q) initial w block
+            dalpha_ref,         # out: (n_p / 128, 128) dual deltas
+            w_ref,              # out: (1, m_q) running w
+            *, lam, n, Q, tr, loss, use_beta, runtime):
+    h = pl.program_id(0)
+
+    @pl.when(h == 0)
+    def _init():
+        w_ref[...] = w0_ref[...].astype(jnp.float32)
+        dalpha_ref[...] = jnp.zeros_like(dalpha_ref)
+
+    i = idx_ref[h]
+    xi = x_ref[pl.ds(i % tr, 1), :].astype(jnp.float32)
+    yi = read_lane(y_ref, i)
+    mi = read_lane(mask_ref, i)
+    a_i = read_lane(alpha_ref, i) + read_lane(dalpha_ref, i)
+    # runtime mode (the fleet path): lam / n arrive as traced scalars in
+    # the prefetch params vector; static mode bakes the Python constants
+    lam_v = params_ref[1] if runtime else lam
+    n_v = params_ref[2] if runtime else n
+
+    w = w_ref[...]
+    zloc = jnp.sum(xi * w, axis=1, keepdims=True)
+    denom = (params_ref[0] if use_beta
+             else jnp.sum(xi * xi, axis=1, keepdims=True))
+    d = sdca_delta(loss, a_i, zloc, yi, denom, lam_v, n_v, Q) * mi
+
+    w_ref[...] = w + (d / (lam_v * n_v)) * xi
+    add_lane(dalpha_ref, i, d)
 
 
 def sdca_epoch_pallas(x, y, mask, alpha0, w0, idx, *, lam, n, Q,
-                      loss: str = "hinge", beta=None, interpret: bool = True):
+                      loss: str = "hinge", beta=None, interpret=None):
     """Drop-in kernel version of ``ref.sdca_epoch_ref``.
 
     x: (n_p, m_q) f32; idx: (steps,) int32.  ``beta`` (a runtime scalar,
     may be traced) selects the paper's step_mode="beta" denominator.
     ``lam`` / ``n`` may also be traced (the fleet's per-tenant path);
     they then ride the same scalar-prefetch vector as beta.
+    ``interpret=None`` follows ``repro.kernels.default_interpret``.
     Returns (dalpha, w_final).
     """
     n_p, m_q = x.shape
     steps = idx.shape[0]
+    tr = row_tile(n_p)
     use_beta = beta is not None
-    runtime = not (_static_scalar(lam) and _static_scalar(n))
+    runtime = not (static_scalar(lam) and static_scalar(n))
     params = jnp.stack([
         jnp.asarray(beta if use_beta else 0.0, jnp.float32),
         jnp.asarray(lam, jnp.float32),
         jnp.asarray(n, jnp.float32)])
+    y2, mask2, alpha2 = to_lanes(y), to_lanes(mask), to_lanes(alpha0)
     kern = functools.partial(
         _kernel,
         lam=None if runtime else float(lam),
         n=None if runtime else int(n),
-        Q=int(Q), steps=steps, loss=loss, use_beta=use_beta,
-        runtime=runtime)
+        Q=int(Q), tr=tr, loss=loss, use_beta=use_beta, runtime=runtime)
+    whole = lambda h, idx_ref, p: (0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(steps,),
         in_specs=[
-            pl.BlockSpec((1, m_q), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, m_q), lambda h, idx_ref, b: (0, 0)),
+            pl.BlockSpec((tr, m_q), lambda h, idx_ref, p: (idx_ref[h] // tr,
+                                                          0)),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec((1, m_q), whole),
         ],
         out_specs=[
-            pl.BlockSpec((n_p, 1), lambda h, idx_ref, b: (0, 0)),
-            pl.BlockSpec((1, m_q), lambda h, idx_ref, b: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, m_q), jnp.float32),
-            pltpu.VMEM((n_p, 1), jnp.float32),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec((1, m_q), whole),
         ],
     )
     dalpha, w_fin = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct(y2.shape, jnp.float32),
             jax.ShapeDtypeStruct((1, m_q), jnp.float32),
         ],
-        interpret=interpret,
-    )(idx, params, x, y[:, None], mask[:, None], alpha0[:, None],
-      w0[None, :])
-    return dalpha[:, 0], w_fin[0]
+        interpret=resolve_interpret(interpret),
+    )(idx, params, x, y2, mask2, alpha2, w0[None, :])
+    return from_lanes(dalpha, n_p), w_fin[0]

@@ -2,19 +2,20 @@
 
 Sparse sibling of ``sdca.sdca_epoch_pallas`` for news20-scale blocks.
 Same TPU scheme -- sequential step grid, scalar-prefetched coordinate
-order driving the row DMA, the primal block and dual deltas resident in
-VMEM -- but the gathered row is the (1, k) ELL row (column ids + values)
-instead of the (1, m_q) dense row, so the per-step DMA traffic scales
-with the row's nonzero count, not the block width.
+order driving the DMA, per-observation vectors lane-dense and resident
+in VMEM -- but what moves per step is the (8, k) tile of ELL rows
+(column ids + values) holding row idx[h], fetched into SMEM, so the
+per-step DMA traffic scales with the rows' nonzero count, not the block
+width.
 
-Inside the step the sparse row is combined with the dense VMEM-resident
-``w`` by gather (``z_loc = sum(vals * w[cols])``) and scatter-ADD
-(``w[cols] += d * vals``).  ELL padding slots carry (col=0, val=0): the
-gather reads w[0] harmlessly and the scatter adds zero, so duplicate
-index-0 slots are inert by construction.  The gather/scatter pair is
-exact in interpret mode (CPU CI); on real TPUs it requires the dynamic
-gather/scatter lowering of recent Mosaic -- real-TPU validation rides
-the same ROADMAP follow-up as the dense kernels.
+The primal block ``w`` is resident in VMEM lane-dense as (m_q / 128,
+128).  The k entries of the row are walked with scalar reads from SMEM:
+the gather ``z_loc = sum(vals * w[cols])`` reads row ``c // 128`` of w
+and keeps lane ``c % 128``, and the scatter-ADD ``w[cols] += d * vals``
+adds at that lane, one entry after another, so duplicate columns
+accumulate exactly as a sequential scatter does.  ELL padding slots
+carry (col=0, val=0): the gather adds 0 * w[0] and the scatter adds
+zero, so they are inert by construction.
 
 Supported losses: hinge (closed form), squared.
 """
@@ -27,122 +28,112 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .sdca import _static_scalar
+from .. import resolve_interpret
+from ..lanes import (LANES, add_lane, from_lanes, lane_mask, read_lane,
+                     row_tile, static_scalar, to_lanes)
+from .sdca import sdca_delta
 
 
 def _kernel(idx_ref,            # scalar prefetch: (steps,) int32
             params_ref,         # scalar prefetch: (3,) f32 [beta, lam, n]
-            cols_row_ref,       # (1, k) gathered ELL column ids
-            vals_row_ref,       # (1, k) gathered ELL values
-            y_row_ref,          # (1, 1) label
-            mask_row_ref,       # (1, 1)
-            alpha_row_ref,      # (1, 1) alpha0[i]
-            w0_ref,             # (1, m_q) initial w block
-            dalpha_ref,         # out: (n_p, 1)
-            w_out_ref,          # out: (1, m_q)
-            w_vmem,             # scratch: (1, m_q) f32
-            dal_vmem,           # scratch: (n_p, 1) f32
-            *, lam, n, Q, steps, loss, use_beta, runtime):
+            cols_ref,           # SMEM (tr, k) ELL column ids, row idx[h]'s tile
+            vals_ref,           # SMEM (tr, k) ELL values
+            y_ref,              # (n_p / 128, 128) labels
+            mask_ref,           # (n_p / 128, 128)
+            alpha_ref,          # (n_p / 128, 128) alpha0
+            xsq_ref,            # (n_p / 128, 128) squared row norms
+            w0_ref,             # (m_q / 128, 128) initial w block
+            dalpha_ref,         # out: (n_p / 128, 128) dual deltas
+            w_ref,              # out: (m_q / 128, 128) running w
+            *, lam, n, Q, k, tr, loss, use_beta, runtime):
     h = pl.program_id(0)
 
     @pl.when(h == 0)
     def _init():
-        w_vmem[...] = w0_ref[...].astype(jnp.float32)
-        dal_vmem[...] = jnp.zeros_like(dal_vmem)
+        w_ref[...] = w0_ref[...]
+        dalpha_ref[...] = jnp.zeros_like(dalpha_ref)
 
     i = idx_ref[h]
-    ci = cols_row_ref[0, :]
-    vi = vals_row_ref[0, :].astype(jnp.float32)
-    yi = y_row_ref[0, 0].astype(jnp.float32)
-    mi = mask_row_ref[0, 0].astype(jnp.float32)
-    a_i = alpha_row_ref[0, 0].astype(jnp.float32) + dal_vmem[i, 0]
+    r = i % tr
+    yi = read_lane(y_ref, i)
+    mi = read_lane(mask_ref, i)
+    a_i = read_lane(alpha_ref, i) + read_lane(dalpha_ref, i)
     # runtime mode (fleet): traced lam / n from the prefetch params;
     # static mode bakes the Python constants (kernel unchanged)
     lam_v = params_ref[1] if runtime else lam
     n_v = params_ref[2] if runtime else n
 
-    w = w_vmem[0, :]
-    zloc = jnp.sum(vi * jnp.take(w, ci, axis=0))
-    x_sq = jnp.sum(vi * vi)
-    denom = params_ref[0] if use_beta else x_sq
-    denom = jnp.maximum(denom, 1e-12)
+    def gather(j, acc):
+        c = cols_ref[r, j]
+        row = w_ref[pl.ds(c // LANES, 1), :]
+        return acc + jnp.where(lane_mask(c), vals_ref[r, j] * row, 0.0)
 
-    if loss == "hinge":
-        d = (yi / Q - zloc) * lam_v * n_v / denom
-        lo = jnp.where(yi > 0, 0.0, -1.0)
-        hi = jnp.where(yi > 0, 1.0, 0.0)
-        d = jnp.clip(a_i + d, lo, hi) - a_i
-    elif loss == "squared":
-        num = yi / Q - a_i / (2.0 * Q) - zloc
-        den = 1.0 / (2.0 * Q) + denom / (lam_v * n_v)
-        d = num / jnp.maximum(den, 1e-12)
-    else:
-        raise ValueError(loss)
-    d = d * mi
+    acc = jax.lax.fori_loop(0, k, gather, jnp.zeros((1, LANES), jnp.float32))
+    zloc = jnp.sum(acc, axis=1, keepdims=True)
+    denom = params_ref[0] if use_beta else read_lane(xsq_ref, i)
+    d = sdca_delta(loss, a_i, zloc, yi, denom, lam_v, n_v, Q) * mi
+    coef = d / (lam_v * n_v)
 
-    w_vmem[0, :] = w.at[ci].add((d / (lam_v * n_v)) * vi)
-    dal_vmem[i, 0] = dal_vmem[i, 0] + d
+    def scatter(j, carry):
+        add_lane(w_ref, cols_ref[r, j], coef * vals_ref[r, j])
+        return carry
 
-    @pl.when(h == steps - 1)
-    def _flush():
-        dalpha_ref[...] = dal_vmem[...]
-        w_out_ref[...] = w_vmem[...]
+    jax.lax.fori_loop(0, k, scatter, 0)
+    add_lane(dalpha_ref, i, d)
 
 
 def sdca_epoch_sparse_pallas(cols, vals, y, mask, alpha0, w0, idx, *, lam, n,
                              Q, loss: str = "hinge", beta=None,
-                             interpret: bool = True):
+                             interpret=None):
     """Sparse-cell kernel version of one local SDCA epoch.
 
     cols/vals: (n_p, k) padded-ELL block; w0: (m_q,) dense primal block;
     idx: (steps,) int32.  ``beta`` (a runtime scalar, may be traced)
     selects the paper's step_mode="beta" denominator; ``lam`` / ``n``
     may also be traced (the fleet's per-tenant path).
+    ``interpret=None`` follows ``repro.kernels.default_interpret``.
     Returns (dalpha, w_final).
     """
     n_p, k = cols.shape
     m_q = w0.shape[0]
     steps = idx.shape[0]
+    tr = row_tile(n_p)
     use_beta = beta is not None
-    runtime = not (_static_scalar(lam) and _static_scalar(n))
+    runtime = not (static_scalar(lam) and static_scalar(n))
     params = jnp.stack([
         jnp.asarray(beta if use_beta else 0.0, jnp.float32),
         jnp.asarray(lam, jnp.float32),
         jnp.asarray(n, jnp.float32)])
+    vals = vals.astype(jnp.float32)
+    y2, mask2, alpha2 = to_lanes(y), to_lanes(mask), to_lanes(alpha0)
+    xsq2 = to_lanes(jnp.sum(vals * vals, axis=1))
+    w2 = to_lanes(w0)
     kern = functools.partial(
         _kernel,
         lam=None if runtime else float(lam),
         n=None if runtime else int(n),
-        Q=int(Q), steps=steps, loss=loss, use_beta=use_beta,
+        Q=int(Q), k=k, tr=tr, loss=loss, use_beta=use_beta,
         runtime=runtime)
+    whole = lambda h, idx_ref, p: (0, 0)  # noqa: E731
+    ell = pl.BlockSpec((tr, k), lambda h, idx_ref, p: (idx_ref[h] // tr, 0),
+                       memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, k), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, b: (idx_ref[h], 0)),
-            pl.BlockSpec((1, m_q), lambda h, idx_ref, b: (0, 0)),
-        ],
+        in_specs=[ell, ell] + [pl.BlockSpec(y2.shape, whole)] * 4
+        + [pl.BlockSpec(w2.shape, whole)],
         out_specs=[
-            pl.BlockSpec((n_p, 1), lambda h, idx_ref, b: (0, 0)),
-            pl.BlockSpec((1, m_q), lambda h, idx_ref, b: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, m_q), jnp.float32),
-            pltpu.VMEM((n_p, 1), jnp.float32),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec(w2.shape, whole),
         ],
     )
     dalpha, w_fin = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_p, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, m_q), jnp.float32),
+            jax.ShapeDtypeStruct(y2.shape, jnp.float32),
+            jax.ShapeDtypeStruct(w2.shape, jnp.float32),
         ],
-        interpret=interpret,
-    )(idx, params, cols, vals, y[:, None], mask[:, None], alpha0[:, None],
-      w0[None, :])
-    return dalpha[:, 0], w_fin[0]
+        interpret=resolve_interpret(interpret),
+    )(idx, params, cols.astype(jnp.int32), vals, y2, mask2, alpha2, xsq2, w2)
+    return from_lanes(dalpha, n_p), from_lanes(w_fin, m_q)

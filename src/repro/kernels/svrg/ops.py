@@ -5,7 +5,6 @@ from functools import partial
 
 import jax
 
-from .. import default_interpret
 from .ref import svrg_inner_ref
 from .svrg import svrg_inner_pallas
 
@@ -18,8 +17,6 @@ def svrg_inner(x_sub, y, mask, z_anchor, w_anchor, mu_sub, idx, *,
     if backend == "ref":
         return svrg_inner_ref(x_sub, y, mask, z_anchor, w_anchor, mu_sub,
                               idx, lam=lam, eta=eta, loss=loss)
-    if interpret is None:
-        interpret = default_interpret()
     return svrg_inner_pallas(x_sub, y, mask, z_anchor, w_anchor, mu_sub,
                              idx, lam=lam, eta=eta, loss=loss,
                              interpret=interpret)

@@ -1,10 +1,12 @@
 """Pallas TPU kernel: RADiSA inner loop (Algorithm 3 steps 7-10).
 
 Same TPU scheme as the SDCA kernel: sequential step grid, scalar-prefetched
-minibatch order driving the row gather (pipelined DMA), sub-block iterate w
-and the anchor quantities resident in VMEM for all L steps.  The step size
-eta_t = gamma / (1 + sqrt(t-1)) changes every outer iteration, so it is a
-runtime scalar-prefetch input rather than a compile-time constant.
+minibatch order driving the DMA of the (8, m_sub) tile that holds each
+sampled row, lane-dense per-observation vectors and the anchor quantities
+resident in VMEM for all L steps, and the sub-block iterate w kept in its
+resident output block.  The step size eta_t = gamma / (1 + sqrt(t-1))
+changes every outer iteration, so it is a runtime scalar-prefetch input
+rather than a compile-time constant.
 """
 from __future__ import annotations
 
@@ -15,8 +17,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+from ..lanes import read_lane, row_tile, static_scalar, to_lanes
 
-def _grad(loss, z, y):
+
+def loss_grad(loss, z, y):
     if loss == "hinge":
         return jnp.where(y * z < 1.0, -y, 0.0)
     if loss == "squared":
@@ -24,65 +29,73 @@ def _grad(loss, z, y):
     raise ValueError(loss)
 
 
-def _kernel(idx_ref, params_ref, x_row_ref, y_row_ref, mask_row_ref,
-            z_row_ref, w_anchor_ref, mu_ref, w_out_ref, w_vmem,
-            *, lam, L, loss, runtime):
+def _kernel(idx_ref,            # scalar prefetch: (L,) int32
+            params_ref,         # scalar prefetch: (2,) f32 [eta, lam]
+            x_ref,              # (tr, m_sub) tile holding row idx[h]
+            y_ref,              # (n_p / 128, 128)
+            mask_ref,           # (n_p / 128, 128)
+            z_ref,              # (n_p / 128, 128) anchor inner products
+            w_anchor_ref,       # (1, m_sub)
+            mu_ref,             # (1, m_sub)
+            w_ref,              # out: (1, m_sub) running iterate
+            *, lam, tr, loss, runtime):
     h = pl.program_id(0)
 
     @pl.when(h == 0)
     def _init():
-        w_vmem[...] = w_anchor_ref[...].astype(jnp.float32)
+        w_ref[...] = w_anchor_ref[...].astype(jnp.float32)
 
-    xj = x_row_ref[0, :].astype(jnp.float32)
-    yj = y_row_ref[0, 0].astype(jnp.float32)
-    mj = mask_row_ref[0, 0].astype(jnp.float32)
-    zj = z_row_ref[0, 0].astype(jnp.float32)
-    wa = w_anchor_ref[0, :].astype(jnp.float32)
-    mu = mu_ref[0, :].astype(jnp.float32)
+    j = idx_ref[h]
+    xj = x_ref[pl.ds(j % tr, 1), :].astype(jnp.float32)
+    yj = read_lane(y_ref, j)
+    mj = read_lane(mask_ref, j)
+    zj = read_lane(z_ref, j)
+    wa = w_anchor_ref[...].astype(jnp.float32)
+    mu = mu_ref[...].astype(jnp.float32)
     # runtime mode (fleet): traced lam from the prefetch params;
     # static mode bakes the Python constant (kernel unchanged)
     lam_v = params_ref[1] if runtime else lam
 
-    w = w_vmem[0, :]
-    z = zj + jnp.sum(xj * (w - wa))
-    g = (_grad(loss, z, yj) - _grad(loss, zj, yj)) * xj * mj \
+    w = w_ref[...]
+    z = zj + jnp.sum(xj * (w - wa), axis=1, keepdims=True)
+    g = (loss_grad(loss, z, yj) - loss_grad(loss, zj, yj)) * xj * mj \
         + mu + lam_v * (w - wa)
-    w_vmem[0, :] = w - params_ref[0] * g
-
-    @pl.when(h == L - 1)
-    def _flush():
-        w_out_ref[...] = w_vmem[...]
+    w_ref[...] = w - params_ref[0] * g
 
 
 def svrg_inner_pallas(x_sub, y, mask, z_anchor, w_anchor, mu_sub, idx, *,
-                      lam, eta, loss: str = "hinge", interpret: bool = True):
-    from repro.kernels.sdca.sdca import _static_scalar
+                      lam, eta, loss: str = "hinge", interpret=None):
+    """Kernel version of ``ref.svrg_inner_ref``: x_sub (n_p, m_sub),
+    idx (L,) int32; ``eta`` and ``lam`` may be traced.  ``interpret=None``
+    follows ``repro.kernels.default_interpret``.  Returns w (m_sub,)."""
     n_p, m_sub = x_sub.shape
     L = idx.shape[0]
-    runtime = not _static_scalar(lam)
+    tr = row_tile(n_p)
+    runtime = not static_scalar(lam)
     params = jnp.stack([jnp.asarray(eta, jnp.float32),
                         jnp.asarray(lam, jnp.float32)])
+    y2, mask2, z2 = to_lanes(y), to_lanes(mask), to_lanes(z_anchor)
     kern = functools.partial(_kernel, lam=None if runtime else float(lam),
-                             L=L, loss=loss, runtime=runtime)
+                             tr=tr, loss=loss, runtime=runtime)
+    whole = lambda h, idx_ref, p: (0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(L,),
         in_specs=[
-            pl.BlockSpec((1, m_sub), lambda h, idx_ref, e: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, e: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, e: (idx_ref[h], 0)),
-            pl.BlockSpec((1, 1), lambda h, idx_ref, e: (idx_ref[h], 0)),
-            pl.BlockSpec((1, m_sub), lambda h, idx_ref, e: (0, 0)),
-            pl.BlockSpec((1, m_sub), lambda h, idx_ref, e: (0, 0)),
+            pl.BlockSpec((tr, m_sub), lambda h, idx_ref, p: (idx_ref[h] // tr,
+                                                            0)),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec(y2.shape, whole),
+            pl.BlockSpec((1, m_sub), whole),
+            pl.BlockSpec((1, m_sub), whole),
         ],
-        out_specs=pl.BlockSpec((1, m_sub), lambda h, idx_ref, e: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, m_sub), jnp.float32)],
+        out_specs=pl.BlockSpec((1, m_sub), whole),
     )
     w = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, m_sub), jnp.float32),
-        interpret=interpret,
-    )(idx, params, x_sub, y[:, None], mask[:, None], z_anchor[:, None],
-      w_anchor[None, :], mu_sub[None, :])
+        interpret=resolve_interpret(interpret),
+    )(idx, params, x_sub, y2, mask2, z2, w_anchor[None, :], mu_sub[None, :])
     return w[0]

@@ -27,7 +27,7 @@ import time
 import jax
 
 from repro.configs import ARCHS, get_config
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import input_specs
 from repro.models.config import LM_SHAPES
 from repro.roofline.hlo import collective_bytes_from_hlo
@@ -69,7 +69,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, save: bool = True):
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         cell = input_specs(cfg, shape, mesh)
         if cell.kind == "train":
             args = (cell.params, cell.opt, cell.batch)
@@ -163,7 +163,7 @@ def run_calibration(arch: str, shape_name: str, save: bool = True):
             cfg, n_layers=layers, unroll=True, attn_impl="full",
             train_accum=1, loss_chunk=None)
         t0 = time.time()
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             cell = input_specs(ccfg, shape, mesh)
             if cell.kind == "train":
                 args = (cell.params, cell.opt, cell.batch)

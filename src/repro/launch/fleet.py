@@ -147,6 +147,9 @@ def main(argv=None):
             + f" --xla_force_host_platform_device_count="
               f"{args.force_host_devices}").strip()
 
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+
     # jax (and everything that imports it) only after the device forcing
     from repro.core import get_solver
     from repro.data import make_sparse_svm_data, make_svm_data

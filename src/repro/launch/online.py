@@ -110,6 +110,9 @@ def main(argv=None):
             + f" --xla_force_host_platform_device_count="
               f"{args.force_host_devices}").strip()
 
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+
     import numpy as np
 
     from repro.core import get_solver, objective

@@ -177,6 +177,9 @@ def main(argv=None):
             + f" --xla_force_host_platform_device_count="
               f"{args.force_host_devices}").strip()
 
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+
     # jax (and everything that imports it) only after the device forcing
     from repro.core import get_solver, objective, serial_sdca
     from repro.data import (load_libsvm, load_libsvm_csr,

@@ -22,6 +22,7 @@ import numpy as np
 from ..configs import get_config
 from ..models import Transformer, reduced
 from ..serve import EngineConfig, InferenceEngine, Request, SamplingParams
+from .compile_cache import use_compile_cache
 
 
 def build_trace(cfg, n_requests, plen_min, plen_max, gen_min, gen_max,
@@ -155,6 +156,7 @@ def main(argv=None):
     from .obs import add_obs_flags
     add_obs_flags(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -24,7 +24,8 @@ from ..models import Transformer, reduced
 from ..optim import AdamWConfig, adamw_init, warmup_cosine
 from ..runtime import Trainer, TrainerConfig
 from ..sharding.rules import batch_axes
-from .mesh import make_mesh, mesh_context
+from .compile_cache import use_compile_cache
+from .mesh import make_mesh
 from .steps import make_train_step, param_shardings
 
 
@@ -43,6 +44,7 @@ def main(argv=None):
                     help="e.g. '4,2' for a 4x2 (data, model) mesh")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
@@ -60,7 +62,7 @@ def main(argv=None):
     model = Transformer(cfg, mesh=mesh)
     opt_cfg = AdamWConfig(lr=warmup_cosine(args.lr, 20, args.steps))
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         pstructs, _, pspecs = param_shardings(model, mesh)
         params = jax.jit(
             lambda k: model.init(k)[0],

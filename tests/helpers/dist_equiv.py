@@ -8,13 +8,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import *
 from repro.data import make_svm_data
+from repro.launch.mesh import make_mesh
 
 def main():
     P_, Q_ = 4, 2
     X, y = make_svm_data(400, 120, seed=1)
     lam = 1.0
     data = partition(X, y, P=P_, Q=Q_)
-    mesh = jax.make_mesh((P_, Q_), ("data", "model"))
+    mesh = make_mesh((P_, Q_), ("data", "model"))
 
     Xd, yd = np.asarray(data.dense()[0]), np.asarray(data.dense()[1])
     n_pad, m_pad = P_ * data.n_p, Q_ * data.m_q
@@ -55,7 +56,7 @@ def main():
     from repro.core.losses import get_loss
     from repro.core.d3ca import make_d3ca_step
     from repro.core.radisa import make_radisa_step
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     daxes = ("pod", "data")
     loss = get_loss("hinge")
     key0 = jax.random.PRNGKey(0)

@@ -5,7 +5,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 
 from repro.configs import ARCHS, get_config
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import input_specs
 from repro.models import Transformer, reduced
 from repro.models.config import ShapeConfig
@@ -16,13 +16,13 @@ SHAPES = [ShapeConfig("t", 64, 8, "train"),
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     fails = []
     for arch in ARCHS:
         cfg = reduced(get_config(arch))
         for shape in SHAPES:
             try:
-                with mesh_context(mesh):
+                with jax.set_mesh(mesh):
                     cell = input_specs(cfg, shape, mesh)
                     if cell.kind == "train":
                         args = (cell.params, cell.opt, cell.batch)

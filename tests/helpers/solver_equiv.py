@@ -38,13 +38,13 @@ max-abs diffs; exits nonzero on failure.
 import os
 import sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax
 import jax.numpy as jnp
 
 from repro.core import (ADMMConfig, D3CAConfig, RADiSAConfig, SFKConfig,
                         get_loss, get_solver, make_radisa_step,
                         objective)
 from repro.data import make_svm_data
+from repro.launch.mesh import make_mesh
 
 Pn, Qn = 4, 2
 
@@ -347,7 +347,7 @@ def main():
     check("d3ca_beta_w", rb.w, rd.w)
 
     # regression: silent trailing-column drop is now a loud error
-    mesh = jax.make_mesh((Pn, Qn), ("data", "model"))
+    mesh = make_mesh((Pn, Qn), ("data", "model"))
     try:
         make_radisa_step(get_loss("hinge"), mesh, RADiSAConfig(lam=lam),
                          n=120, n_p=30, m_q=21)

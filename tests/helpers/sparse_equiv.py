@@ -13,12 +13,12 @@ on failure.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax
 import jax.numpy as jnp
 
 from repro.core import (ADMMConfig, D3CAConfig, RADiSAConfig, get_solver,
                         prepare_shard_map_sparse)
 from repro.data import csr_from_dense, make_sparse_svm_data
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -65,7 +65,7 @@ def main():
                 check(f"{label}_{backend}_alpha", rb.alpha, rd.alpha)
 
     # device buffers are ELL-sized: k ~ max row nnz, nowhere near m_q
-    mesh = jax.make_mesh((Pn, Qn), ("data", "model"))
+    mesh = make_mesh((Pn, Qn), ("data", "model"))
     sdata = prepare_shard_map_sparse(mesh, Xcsr, y, m_multiple=Pn * Qn)
     print(f"ell k={sdata.k} m_q={sdata.m_q} "
           f"cols={sdata.cols.shape} vals={sdata.vals.shape}")

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager, restore_tree, save_tree
+from repro.launch.mesh import make_mesh
 
 
 def _tree(key=0):
@@ -55,11 +56,11 @@ def test_atomic_no_partial_dirs(tmp_path):
 def test_elastic_reshard(tmp_path):
     """Save under one sharding, restore under another (mesh change)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh1 = make_mesh((1,), ("data",))
     x = jax.device_put(jnp.arange(16.0).reshape(4, 4),
                        NamedSharding(mesh1, P("data")))
     save_tree(str(tmp_path / "ck"), {"x": x})
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
     tgt = NamedSharding(mesh2, P(None, "model"))
     r = restore_tree(str(tmp_path / "ck"), {"x": jnp.zeros((4, 4))},
                      shardings={"x": tgt})

@@ -17,6 +17,7 @@ from repro.core.comm import (Collective, CommSchedule, OverlapComm,
 from repro.core.comm_model import Topology
 from repro.core.compress import get_codec
 from repro.core.engines import CellProgram, grid_program, mesh_program
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -125,7 +126,7 @@ def _delay_program():
 
 @pytest.mark.parametrize("tau", [1, 2, 3])
 def test_stale_comm_bounded_delay(tau):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cellprog = _delay_program()
     data = jnp.ones((1,))
     state0 = jnp.zeros((1,))
@@ -149,7 +150,7 @@ def test_stale_comm_bounded_delay(tau):
 
 
 def test_stale_tau0_is_sync():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cellprog = _delay_program()
     data = jnp.ones((1,))
     state0 = jnp.zeros((1,))
@@ -173,7 +174,7 @@ def test_stale_warmup_pins_first_reduction():
     consume step 1's value -- never zeros from initialization, never a
     partially-filled ring."""
     tau = 3
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     data = jnp.ones((1,))
     step, comm0, _ = mesh_program(_delay_program(), mesh, data,
                                   jnp.zeros((1,)), staleness=tau)
@@ -196,7 +197,7 @@ def test_overlap_comm_matches_stale_delay(tau):
     """The overlap engine changes wall-clock, never numerics: at every
     tau its per-step outputs equal StaleComm's bit for bit (tau = 0 is
     the sync engine)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     data = jnp.ones((1,))
     state0 = jnp.zeros((1,))
     step_s, comm_s, _ = mesh_program(_delay_program(), mesh, data, state0,
@@ -225,7 +226,7 @@ def test_wire_bytes_additive_across_executors():
     """Byte accounting is additive, not policy-dependent: the staleness
     ring only re-times consumption, so sync / stale / overlap report
     identical totals for the identity wire."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     data = jnp.ones((1,))
     state0 = jnp.zeros((1,))
     accts = {}
