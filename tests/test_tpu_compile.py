@@ -26,8 +26,13 @@ ROOT = Path(__file__).resolve().parents[1]
 
 #: paper Part 1 "4x2": 2,000 x 3,000 blocks; RADiSA's sub-block is m_q / P
 PART1 = dict(n=8000, P=4, Q=2, n_p=2000, m_q=3000)
-#: real-sim-shaped cell on a 4x2 grid (72,309 x 20,958 at 2.4e-3)
-REALSIM = dict(n=72309, n_p=18080, k=128, m_q=10480)
+#: real-sim-shaped cell on a 4x2 grid (72,309 x 20,958 at 2.4e-3), as
+#: ``partition_sparse`` cuts it: n_p = 72,312 / 4 is not a multiple of 8,
+#: so the last (8, k) ELL tile and the last dense tile run past the
+#: array; k = 56 is the widest row's nonzeros in one feature block at
+#: seed 0; m_q = 20,958 / 2 for D3CA, and 20,960 / 2 for RADiSA, which
+#: pads the features to a multiple of P * Q
+REALSIM = dict(n=72309, n_p=18078, k=56, m_q=10479, m_q_radisa=10480)
 
 
 @pytest.fixture(scope="module")
@@ -109,7 +114,7 @@ def test_sdca_sparse_compiles(spec):
 
 def test_svrg_sparse_compiles(spec):
     from repro.kernels.svrg import svrg_inner_sparse_pallas
-    n_p, k, m_sub = REALSIM["n_p"], REALSIM["k"], REALSIM["m_q"] // 4
+    n_p, k, m_sub = REALSIM["n_p"], REALSIM["k"], REALSIM["m_q_radisa"] // 4
 
     def inner(cols, vals, y, mask, z, wa, mu, idx, eta, lo):
         return svrg_inner_sparse_pallas(cols, vals, y, mask, z, wa, mu, idx,
