@@ -1,0 +1,32 @@
+"""The dense SDCA kernel's share of its roofline: the least time the
+chip needs for the epochs' required ops and bytes
+(``chipbench.cost.sdca_dense``, at ``peaks.json``), over the kernel's
+summed device time in the traced window."""
+from __future__ import annotations
+
+from chipbench import trace_reduce as tr
+from chipbench.cost import least_seconds
+from chipbench.cost.sdca_dense import epoch
+
+#: how the kernel is found in the trace: the op text of a Mosaic kernel.
+#: Pallas kernels carry no name of their own there today (each is a
+#: ``closed_call`` custom call), and this cell runs no other
+KERNELS = (r'custom_call_target="tpu_custom_call"',)
+
+
+def read(ctx):
+    lo, hi = ctx.window
+    kernel_ns = sum(e.duration for dev in ctx.trace.devices[:ctx.chips]
+                    for e in tr.within(dev.ops, lo, hi)
+                    if tr.matches(e, KERNELS))
+    if kernel_ns <= 0 or not ctx.iters:
+        return None
+    P, Q = ctx.grid
+    n, m = ctx.problem.n, ctx.problem.m
+    n_p, m_q = -(-n // P), -(-m // Q)
+    ops, nbytes = epoch(n_p, m_q, steps=ctx.steps)
+    least, bound = least_seconds(ops, nbytes, ctx.peaks)
+    least *= P * Q * ctx.iters
+    return {"value": 100.0 * least / (kernel_ns * 1e-9),
+            "note": {"bound": bound, "least_s": least,
+                     "kernel_s": kernel_ns * 1e-9}}
