@@ -107,7 +107,7 @@ def trace_overhead(name, cfg, X, y, P, Q, iters, reps):
     run(Tracer())                                        # warm both paths
     untraced = min(run(None) for _ in range(reps))
     traced = min(run(Tracer()) for _ in range(reps))
-    # capacity < spans per run (2/iter: outer_iter + step) => the whole
+    # capacity < spans per run (2/iter: repro.iter + repro.step) => the whole
     # run exercises the at-capacity drop path
     recorded = min(run(FlightRecorder(capacity=max(2, iters)))
                    for _ in range(reps))

@@ -6,13 +6,15 @@ Chrome-trace you can open in https://ui.perfetto.dev.
 
 What it shows:
 
-  * ``Tracer`` spans around the whole solve (data prep, every outer
-    iteration, the synthesized per-collective spans named after the
-    solver's declared ``CommSchedule`` collectives);
+  * ``Tracer`` spans around the whole solve (``repro.prep`` and its
+    cut / send / bind, every outer iteration's step and observation
+    with its primal and dual evaluations) -- the same spans, with their
+    counters, that a ``jax.profiler`` trace of the solve shows;
   * a ``Registry`` collecting the same run as counters / gauges /
     histograms -- the one snapshot schema the BENCH emitters embed;
   * the per-iteration ``step_s`` / ``local_s`` / ``comm_s`` / ``host_s``
-    fields that telemetry adds to ``SolveResult.history``.
+    fields that telemetry adds to ``SolveResult.history`` (the local /
+    comm split comes from the registry's phase calibration).
 """
 import argparse
 import json
@@ -53,13 +55,15 @@ def main():
               f"   f={h['objective']:.6f}")
 
     # 2. span totals straight off the tracer
-    solve_s = tracer.total("solve")
+    solve_s = tracer.total("repro.solve")
     print(f"\nspan totals over {solve_s * 1e3:.1f} ms of solve:")
-    for name in ("data_prep", "calibrate", "outer_iter", "step",
-                 "local_solve", "comm/dalpha", "comm/w_contrib",
-                 "observe"):
+    for name in ("repro.prep", "repro.prep.partition", "repro.prep.transfer",
+                 "repro.prep.bind", "repro.calibrate", "repro.iter",
+                 "repro.step", "repro.observe", "repro.observe.primal",
+                 "repro.observe.dual", "repro.result"):
         t = tracer.total(name)
-        print(f"  {name:<14s} {t * 1e3:8.2f} ms  ({100 * t / solve_s:5.1f}%)")
+        print(f"  {name:<22s} {t * 1e3:8.2f} ms  "
+              f"({100 * t / solve_s:5.1f}%)")
 
     # 3. the registry snapshot -- the same schema BENCH emitters embed
     snap = reg.snapshot()

@@ -49,12 +49,13 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from ..obs.trace import PROFILER_TRACER, as_tracer
 from .comm import (CommSchedule, LocalComm, OverlapComm, ShapeProbeComm,
                    StaleComm, SyncComm, hier_ef_names)
 from .comm_model import hierarchical_accounting
 from .compress import CompressedComm, get_codec, wire_accounting
 from .partition import _ceil_to
-from .util import as_axes, axes_size, pvary, shard_map
+from .util import as_axes, axes_size, host_nbytes, pvary, shard_map
 
 
 @dataclasses.dataclass
@@ -118,65 +119,56 @@ def drive(prog: EngineProgram, outer_iters: int, observe=None, *,
     every step; returning True stops early.  Returns
     (final state, iterations run, stopped_early).
 
-    Telemetry (all optional, default off -- the untimed loop is
-    bit-identical to the pre-telemetry driver and adds no syncs):
+    Each iteration is a ``repro.iter`` span holding ``repro.step`` (the
+    step's dispatch) and ``repro.observe`` spans, each with its ``iter``.
 
-      * ``tracer`` -- a :class:`repro.obs.trace.Tracer`; each iteration
-        becomes an ``outer_iter`` span with ``step`` / ``observe``
-        children, and the step blocks on its device result so the span
-        measures real device wall-clock;
-      * ``on_step(t, t_begin, step_s)`` -- fires after every timed step
-        (the solver driver uses it to synthesize per-collective
-        attribution spans and feed per-iter phase fields into history);
+      * ``tracer`` -- a :class:`repro.obs.trace.Tracer`; default the
+        profiler-only :data:`~repro.obs.trace.PROFILER_TRACER`, under
+        which the loop adds no sync and the device side of a step is its
+        own module in a profile, on the same clock.  A tracer that keeps
+        events (``enabled``) makes each step block on its device result,
+        so its ``repro.step`` measures the device step;
+      * ``on_step(t, step_s)`` -- fires after every step, which is then
+        timed and blocked on (the solver driver feeds its registry and
+        the per-iter history fields from it);
       * ``monitor`` -- a :class:`repro.obs.health.HealthMonitor`; its
         rate-limited ``poll()`` runs once per iteration (a clock read
         when not due -- health rules only *read* the registry, so the
         iterates are untouched).
+
+    Spans, timing and blocking never change the iterates: every path
+    runs the same steps in the same order.
     """
-    tracing = tracer is not None and getattr(tracer, "enabled", False)
+    tr = as_tracer(tracer, PROFILER_TRACER)
+    timed = tr.enabled or on_step is not None
+    clock = tr.clock if tr.enabled else time.perf_counter
     state = prog.state
-    done = 0
     # The overlap engine's contract: never block on in-flight reduction
     # slots between steps -- only the iterate substate is synced, so a
     # dispatched collective stays a future until the slot is read tau
     # steps later.  sync_of is None for every other engine (block on
     # the whole state, the pre-overlap behavior).
     sync = prog.sync_of if prog.sync_of is not None else (lambda s: s)
-    if not tracing and on_step is None:
-        for t in range(1, outer_iters + 1):
-            state = prog.step(t, state)
-            done = t
-            if monitor is not None:
-                monitor.poll()
-            if observe is not None and observe(t, state):
-                return state, done, True
-        return state, done, False
-
-    if tracing:
-        tr, clock = tracer, tracer.clock
-    else:
-        from repro.obs.trace import NULL_TRACER
-        tr, clock = NULL_TRACER, time.perf_counter
     for t in range(1, outer_iters + 1):
-        with tr.span("outer_iter", iter=t):
-            with tr.span("step", iter=t):
-                # t0 taken INSIDE the span so the attribution spans
-                # on_step synthesizes at t0 nest within it
-                t0 = clock()
-                state = prog.step(t, state)
-                jax.block_until_ready(sync(state))
-                step_s = clock() - t0
+        with tr.span("repro.iter", iter=t):
+            with tr.span("repro.step", iter=t):
+                if timed:
+                    t0 = clock()
+                    state = prog.step(t, state)
+                    jax.block_until_ready(sync(state))
+                    step_s = clock() - t0
+                else:
+                    state = prog.step(t, state)
             if on_step is not None:
-                on_step(t, t0, step_s)
-            done = t
+                on_step(t, step_s)
             if monitor is not None:
                 monitor.poll()
             if observe is not None:
-                with tr.span("observe", iter=t):
+                with tr.span("repro.observe", iter=t):
                     stop = observe(t, state)
                 if stop:
-                    return state, done, True
-    return state, done, False
+                    return state, t, True
+    return state, outer_iters, False
 
 
 def drive_with_callback(prog: EngineProgram, outer_iters: int, callback=None,
@@ -314,41 +306,50 @@ class SparseShardMapData:
 def prepare_shard_map_sparse(mesh, X, y, *, data_axis="data",
                              model_axis="model",
                              m_multiple: int | None = None,
-                             k_multiple: int = 8) -> SparseShardMapData:
+                             k_multiple: int = 8,
+                             tracer=None) -> SparseShardMapData:
     """Sparse analogue of :func:`prepare_shard_map`.
 
     ``X`` is a :class:`~repro.data.sparse.CSRMatrix` (or a dense array,
     converted).  Padding matches ``partition_sparse`` bit-for-bit, so a
     shard_map cell sees the same ELL block as the simulated grid's cell.
+    The host's ELL build is a ``repro.prep.partition`` span with the ELL
+    counters, the puts a ``repro.prep.transfer`` span, in ``tracer``
+    (default the profiler-only tracer).
     """
     from repro.data.sparse import CSRMatrix, csr_from_dense
-    from .partition import _ceil_to as ceil_to, _ell_blocks
-    if not isinstance(X, CSRMatrix):
-        X = csr_from_dense(np.asarray(X))
+    from .partition import _ceil_to as ceil_to, _ell_blocks, ell_counts
+    tr = as_tracer(tracer, PROFILER_TRACER)
     Pn = axes_size(mesh, data_axis)
     Qn = axes_size(mesh, model_axis)
     if m_multiple is not None and m_multiple % Qn:
         raise ValueError(f"m_multiple={m_multiple} not a multiple of Q={Qn}")
-    n, m = X.shape
-    m_pad = ceil_to(m, m_multiple or Qn)
-    cols, vals, y_blocks, mask_blocks = _ell_blocks(
-        X, y, Pn, Qn, m_pad, k_multiple)
-    _, _, n_p, k = cols.shape
-    # (P, Q, n_p, k) -> (P*n_p, Q*k): block (p, q) lands at the
-    # [p*n_p:(p+1)*n_p, q*k:(q+1)*k] tile, which the (data, model)
-    # sharding assigns to device (p, q)
-    cols_g = cols.transpose(0, 2, 1, 3).reshape(Pn * n_p, Qn * k)
-    vals_g = vals.transpose(0, 2, 1, 3).reshape(Pn * n_p, Qn * k)
+    with tr.span("repro.prep.partition") as span:
+        if not isinstance(X, CSRMatrix):
+            X = csr_from_dense(np.asarray(X))
+        n, m = X.shape
+        m_pad = ceil_to(m, m_multiple or Qn)
+        cols, vals, y_blocks, mask_blocks = _ell_blocks(
+            X, y, Pn, Qn, m_pad, k_multiple)
+        span.set_metadata(**ell_counts(cols.shape, X.nnz))
+        _, _, n_p, k = cols.shape
+        # (P, Q, n_p, k) -> (P*n_p, Q*k): block (p, q) lands at the
+        # [p*n_p:(p+1)*n_p, q*k:(q+1)*k] tile, which the (data, model)
+        # sharding assigns to device (p, q)
+        cols_g = cols.transpose(0, 2, 1, 3).reshape(Pn * n_p, Qn * k)
+        vals_g = vals.transpose(0, 2, 1, 3).reshape(Pn * n_p, Qn * k)
     daxes = as_axes(data_axis)
     put = _putter(mesh)
-    return SparseShardMapData(
-        mesh=mesh,
-        cols=put(jnp.asarray(cols_g), P(daxes, model_axis)),
-        vals=put(jnp.asarray(vals_g), P(daxes, model_axis)),
-        y=put(jnp.asarray(y_blocks.reshape(-1)), P(daxes)),
-        mask=put(jnp.asarray(mask_blocks.reshape(-1)), P(daxes)),
-        n=n, m=m, m_q=m_pad // Qn, P=Pn, Q=Qn,
-        data_axis=data_axis, model_axis=model_axis)
+    with tr.span("repro.prep.transfer",
+                 bytes=host_nbytes(cols_g, vals_g, y_blocks, mask_blocks)):
+        return SparseShardMapData(
+            mesh=mesh,
+            cols=put(jnp.asarray(cols_g), P(daxes, model_axis)),
+            vals=put(jnp.asarray(vals_g), P(daxes, model_axis)),
+            y=put(jnp.asarray(y_blocks.reshape(-1)), P(daxes)),
+            mask=put(jnp.asarray(mask_blocks.reshape(-1)), P(daxes)),
+            n=n, m=m, m_q=m_pad // Qn, P=Pn, Q=Qn,
+            data_axis=data_axis, model_axis=model_axis)
 
 
 def _putter(mesh):
@@ -954,31 +955,37 @@ def mesh_local_step(cellprog: CellProgram, mesh, *, data_axis="data",
 
 
 def prepare_shard_map(mesh, X, y, *, data_axis="data", model_axis="model",
-                      m_multiple: int | None = None) -> ShardMapData:
+                      m_multiple: int | None = None,
+                      tracer=None) -> ShardMapData:
     """Pad (X, y) so the mesh divides both axes and place the shards.
 
     The padding rule is identical to ``partition(..., m_multiple=P*Q)``,
     so a shard_map cell sees the same (n_p, m_q) block as the simulated
-    grid's cell (p, q)."""
+    grid's cell (p, q).  The host's padding is a ``repro.prep.partition``
+    span, the puts a ``repro.prep.transfer`` span, in ``tracer`` (default
+    the profiler-only tracer)."""
+    tr = as_tracer(tracer, PROFILER_TRACER)
     Pn = axes_size(mesh, data_axis)
     Qn = axes_size(mesh, model_axis)
     if m_multiple is not None and m_multiple % Qn:
         raise ValueError(f"m_multiple={m_multiple} not a multiple of Q={Qn}")
-    n, m = X.shape
-    n_pad = _ceil_to(n, Pn)
-    m_pad = _ceil_to(m, m_multiple or Qn)
-    Xp = np.zeros((n_pad, m_pad), np.float32)
-    Xp[:n, :m] = np.asarray(X, np.float32)
-    yp = np.zeros((n_pad,), np.float32)
-    yp[:n] = np.asarray(y, np.float32)
-    maskp = np.zeros((n_pad,), np.float32)
-    maskp[:n] = 1.0
+    with tr.span("repro.prep.partition"):
+        n, m = X.shape
+        n_pad = _ceil_to(n, Pn)
+        m_pad = _ceil_to(m, m_multiple or Qn)
+        Xp = np.zeros((n_pad, m_pad), np.float32)
+        Xp[:n, :m] = np.asarray(X, np.float32)
+        yp = np.zeros((n_pad,), np.float32)
+        yp[:n] = np.asarray(y, np.float32)
+        maskp = np.zeros((n_pad,), np.float32)
+        maskp[:n] = 1.0
     daxes = as_axes(data_axis)
     put = _putter(mesh)
-    return ShardMapData(
-        mesh=mesh,
-        x=put(jnp.asarray(Xp), P(daxes, model_axis)),
-        y=put(jnp.asarray(yp), P(daxes)),
-        mask=put(jnp.asarray(maskp), P(daxes)),
-        n=n, m=m, P=Pn, Q=Qn,
-        data_axis=data_axis, model_axis=model_axis)
+    with tr.span("repro.prep.transfer", bytes=host_nbytes(Xp, yp, maskp)):
+        return ShardMapData(
+            mesh=mesh,
+            x=put(jnp.asarray(Xp), P(daxes, model_axis)),
+            y=put(jnp.asarray(yp), P(daxes)),
+            mask=put(jnp.asarray(maskp), P(daxes)),
+            n=n, m=m, P=Pn, Q=Qn,
+            data_axis=data_axis, model_axis=model_axis)

@@ -21,6 +21,9 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import PROFILER_TRACER, as_tracer
+from .util import host_nbytes
+
 
 def _ceil_to(x: int, k: int) -> int:
     return (x + k - 1) // k * k
@@ -74,30 +77,38 @@ class DoublyPartitioned:
 
 
 def partition(X, y, P: int, Q: int, *,
-              m_multiple: int | None = None) -> DoublyPartitioned:
+              m_multiple: int | None = None,
+              tracer=None) -> DoublyPartitioned:
     """Split (X, y) into the P x Q doubly distributed block grid.
 
     ``m_multiple`` pads the feature dimension to a multiple of that value
     instead of just Q.  The solver framework passes P*Q so that RADiSA's
     P sub-blocks divide every feature block and both engines see
     bit-identical blocks.
+
+    The whole cut is a ``repro.prep.partition`` span in ``tracer``
+    (default the profiler-only tracer); sending (X, y) to the device,
+    where the blocks are cut, is a ``repro.prep.transfer`` span inside it.
     """
-    X = jnp.asarray(X)
-    y = jnp.asarray(y)
+    tr = as_tracer(tracer, PROFILER_TRACER)
     if m_multiple is not None and m_multiple % Q:
         raise ValueError(f"m_multiple={m_multiple} not a multiple of Q={Q}")
-    n, m = X.shape
-    n_pad, m_pad = _ceil_to(n, P), _ceil_to(m, m_multiple or Q)
-    n_p, m_q = n_pad // P, m_pad // Q
+    with tr.span("repro.prep.partition"):
+        with tr.span("repro.prep.transfer", bytes=host_nbytes(X, y)):
+            X = jnp.asarray(X)
+            y = jnp.asarray(y)
+        n, m = X.shape
+        n_pad, m_pad = _ceil_to(n, P), _ceil_to(m, m_multiple or Q)
+        n_p, m_q = n_pad // P, m_pad // Q
 
-    Xp = jnp.zeros((n_pad, m_pad), X.dtype).at[:n, :m].set(X)
-    yp = jnp.zeros((n_pad,), y.dtype).at[:n].set(y)
-    mask = jnp.zeros((n_pad,), X.dtype).at[:n].set(1.0)
+        Xp = jnp.zeros((n_pad, m_pad), X.dtype).at[:n, :m].set(X)
+        yp = jnp.zeros((n_pad,), y.dtype).at[:n].set(y)
+        mask = jnp.zeros((n_pad,), X.dtype).at[:n].set(1.0)
 
-    x_blocks = Xp.reshape(P, n_p, Q, m_q).transpose(0, 2, 1, 3)
-    y_blocks = yp.reshape(P, n_p)
-    mask_blocks = mask.reshape(P, n_p)
-    return DoublyPartitioned(x_blocks, y_blocks, mask_blocks, n, m, P, Q)
+        x_blocks = Xp.reshape(P, n_p, Q, m_q).transpose(0, 2, 1, 3)
+        y_blocks = yp.reshape(P, n_p)
+        mask_blocks = mask.reshape(P, n_p)
+        return DoublyPartitioned(x_blocks, y_blocks, mask_blocks, n, m, P, Q)
 
 
 # ---------------------------------------------------------------------------
@@ -238,27 +249,48 @@ class SparseDoublyPartitioned:
         return X[: self.n, : self.m], y[: self.n]
 
 
+def ell_counts(shape, nnz: int) -> dict:
+    """The ELL counters of a grid of padded-ELL cells of ``shape`` (``(P,
+    Q, n_p, k)``) holding ``nnz`` entries: ``ell_k``, the slots that
+    hold an entry (``useful_nnz``) and the padding slots
+    (``padded_slots``)."""
+    slots = int(np.prod(shape))
+    return {"ell_k": int(shape[-1]), "useful_nnz": int(nnz),
+            "padded_slots": slots - int(nnz)}
+
+
 def partition_sparse(X, y, P: int, Q: int, *, m_multiple: int | None = None,
-                     k_multiple: int = 8) -> SparseDoublyPartitioned:
+                     k_multiple: int = 8,
+                     tracer=None) -> SparseDoublyPartitioned:
     """Split (X, y) into the sparse P x Q padded-ELL block grid.
 
     ``X`` may be a :class:`~repro.data.sparse.CSRMatrix` (preferred --
     never densifies) or a dense array (converted row-wise).  The padding
     rule matches ``partition(..., m_multiple=...)`` exactly, so sparse
     and dense runs see the same logical blocks.
+
+    The host's ELL build is a ``repro.prep.partition`` span carrying
+    :func:`ell_counts`, sending the cells a ``repro.prep.transfer`` span,
+    in ``tracer`` (default the profiler-only tracer).
     """
     from repro.data.sparse import CSRMatrix, csr_from_dense
-    if not isinstance(X, CSRMatrix):
-        X = csr_from_dense(np.asarray(X))
+    tr = as_tracer(tracer, PROFILER_TRACER)
     if m_multiple is not None and m_multiple % Q:
         raise ValueError(f"m_multiple={m_multiple} not a multiple of Q={Q}")
-    n, m = X.shape
-    m_pad = _ceil_to(m, m_multiple or Q)
-    cols, vals, y_blocks, mask = _ell_blocks(X, y, P, Q, m_pad, k_multiple)
-    return SparseDoublyPartitioned(
-        cols=jnp.asarray(cols), vals=jnp.asarray(vals),
-        y_blocks=jnp.asarray(y_blocks), mask=jnp.asarray(mask),
-        n=n, m=m, m_q=m_pad // Q, P=P, Q=Q)
+    with tr.span("repro.prep.partition") as span:
+        if not isinstance(X, CSRMatrix):
+            X = csr_from_dense(np.asarray(X))
+        n, m = X.shape
+        m_pad = _ceil_to(m, m_multiple or Q)
+        cols, vals, y_blocks, mask = _ell_blocks(X, y, P, Q, m_pad,
+                                                 k_multiple)
+        span.set_metadata(**ell_counts(cols.shape, X.nnz))
+    with tr.span("repro.prep.transfer",
+                 bytes=host_nbytes(cols, vals, y_blocks, mask)):
+        return SparseDoublyPartitioned(
+            cols=jnp.asarray(cols), vals=jnp.asarray(vals),
+            y_blocks=jnp.asarray(y_blocks), mask=jnp.asarray(mask),
+            n=n, m=m, m_q=m_pad // Q, P=P, Q=Q)
 
 
 def numpy_partition_indices(n: int, P: int):

@@ -82,7 +82,7 @@ from .radisa import (RADiSAConfig, make_radisa_step,
 from .reference import rel_opt
 from .sfk import (SFKConfig, make_sfk_step, sfk_shard_map_program,
                   sfk_simulated_program)
-from .util import axes_size
+from .util import axes_size, host_nbytes
 
 ENGINES = ("simulated", "shard_map", "async", "overlap")
 #: "sync" names today's synchronous mesh policy explicitly (the
@@ -101,7 +101,8 @@ class SolveResult:
     history: List[Dict[str, float]]  # per-iter: iter, time_s, objective,
     #                                  [duality_gap], [rel_opt]; timed
     #                                  solves (tracer=/registry=) add
-    #                                  step_s, local_s, comm_s, host_s
+    #                                  step_s, host_s; registry= adds
+    #                                  local_s, comm_s
     iters: int                      # outer iterations actually run
     converged: bool                 # True iff early stopping triggered
     solver: str
@@ -241,7 +242,7 @@ class Solver:
     def program(self, loss_name: str, X, y, *, P: int = None, Q: int = None,
                 cfg=None, mesh=None, warm_start=None,
                 data_axis="data", model_axis: str = "model",
-                row_gate=None) -> EngineProgram:
+                row_gate=None, tracer=None) -> EngineProgram:
         """Bind the solver to data under the configured engine/backend.
 
         Pads the feature dimension to a multiple of P*Q (identically for
@@ -266,6 +267,12 @@ class Solver:
             dual updates to gated-on rows -- the incremental
             online-update path.  Only solvers with
             ``supports_row_gate`` accept it.
+          tracer: a :class:`repro.obs.Tracer` (default the profiler-only
+            tracer) taking the ``repro.prep.partition`` (cutting the
+            blocks, with the ELL counters), ``repro.prep.transfer``
+            (each put of host arrays, with their ``bytes``) and
+            ``repro.prep.bind`` (building the program, with its
+            program-cache ``cache`` hit / miss / off) spans.
 
         Returns:
           An :class:`EngineProgram` ready for :func:`engines.drive`.
@@ -275,6 +282,8 @@ class Solver:
             unsupported ``row_gate``, or a topology that does not
             divide P.
         """
+        from repro.obs.trace import PROFILER_TRACER, as_tracer
+        tr = as_tracer(tracer, PROFILER_TRACER)
         loss = get_loss(loss_name)
         cfg = cfg if cfg is not None else self.config_cls()
         if row_gate is not None and not self.supports_row_gate:
@@ -282,26 +291,38 @@ class Solver:
                 f"solver {self.name!r} has no incremental row-gate path; "
                 "gated warm-started passes are a dual-solver feature "
                 "(use 'd3ca')")
-        gate_kw = {} if row_gate is None else {"row_gate": row_gate}
         cache = self._build_cache(loss_name, cfg, X, P, Q, mesh,
                                   row_gate is not None)
         w0, alpha0 = _unpack_warm_start(warm_start)
+        starts = (w0, alpha0, row_gate)
+        if self.engine == "simulated" and host_nbytes(*starts):
+            # the grid builders take the starting iterates (and the gate)
+            # as device arrays; the mesh engines pad and place host ones
+            # themselves, inside their bind
+            import jax
+            import jax.numpy as jnp
+            with tr.span("repro.prep.transfer", bytes=host_nbytes(*starts)):
+                w0, alpha0, row_gate = (
+                    a if a is None or isinstance(a, jax.Array)
+                    else jnp.asarray(a) for a in starts)
+        gate_kw = {} if row_gate is None else {"row_gate": row_gate}
+        hit = "off" if cache is None else "hit" if cache else "miss"
         sparse = self.block_format == "sparse"
         topo = self.topology
         pods = topo.pods if topo is not None else 1
         if not sparse and hasattr(X, "toarray"):
-            X = X.toarray()       # CSR input under block_format="dense"
+            with tr.span("repro.prep.partition"):
+                X = X.toarray()   # CSR input under block_format="dense"
         if self.engine == "simulated":
             if P is None or Q is None:
                 raise ValueError("engine='simulated' needs P and Q")
             if pods > 1 and P % pods:
                 raise ValueError(f"topology pods={pods} must divide P={P}")
-            if sparse:
-                data = partition_sparse(X, y, P, Q, m_multiple=P * Q)
-            else:
-                data = partition(X, y, P, Q, m_multiple=P * Q)
-            return self._simulated_program(loss, data, cfg, w0, alpha0,
-                                           cache=cache, **gate_kw)
+            cut = partition_sparse if sparse else partition
+            data = cut(X, y, P, Q, m_multiple=P * Q, tracer=tr)
+            with tr.span("repro.prep.bind", cache=hit):
+                return self._simulated_program(loss, data, cfg, w0, alpha0,
+                                               cache=cache, **gate_kw)
         if mesh is None:
             if P is None or Q is None:
                 raise ValueError(f"engine={self.engine!r} needs a mesh "
@@ -326,10 +347,11 @@ class Solver:
             raise ValueError(f"mesh is {Pn}x{Qn} but P={P}, Q={Q} requested")
         prep = prepare_shard_map_sparse if sparse else prepare_shard_map
         sdata = prep(mesh, X, y, data_axis=data_axis,
-                     model_axis=model_axis, m_multiple=Pn * Qn)
-        return self._shard_map_program(loss, sdata, cfg, w0, alpha0,
-                                       staleness=self.staleness,
-                                       cache=cache, **gate_kw)
+                     model_axis=model_axis, m_multiple=Pn * Qn, tracer=tr)
+        with tr.span("repro.prep.bind", cache=hit):
+            return self._shard_map_program(loss, sdata, cfg, w0, alpha0,
+                                           staleness=self.staleness,
+                                           cache=cache, **gate_kw)
 
     # ---- the shared outer driver ------------------------------------------
     def solve(self, loss_name: str, X, y, *, P: int = None, Q: int = None,
@@ -360,8 +382,10 @@ class Solver:
             field and rel-opt early stopping.
           record_history: collect per-iteration history entries.
           callback: ``callback(t, w, alpha)`` per outer iteration.
-          tracer: a :class:`repro.obs.Tracer` (enables the timed path).
-          registry: a :class:`repro.obs.Registry` for per-iter metrics.
+          tracer: a :class:`repro.obs.Tracer` (enables the timed path;
+            default the profiler-only tracer, which adds no sync).
+          registry: a :class:`repro.obs.Registry` for per-iter metrics
+            (enables the timed path and the phase calibration).
           monitor: a :class:`repro.obs.HealthMonitor`; polled once per
             outer iteration (rules read the registry only -- iterates
             are untouched).
@@ -492,55 +516,63 @@ class Solver:
         convergence metric; the result is then a warm-start point, not
         a converged solve).
 
+        Spans: the solve is a ``repro.solve`` span holding ``repro.prep``
+        (:meth:`program`'s ``repro.prep.partition`` /
+        ``repro.prep.transfer`` / ``repro.prep.bind``), one ``repro.iter``
+        per outer iteration (:func:`~repro.core.engines.drive`'s
+        ``repro.step`` and ``repro.observe``; the latter holds
+        ``repro.observe.primal`` and ``repro.observe.dual``, one per
+        objective evaluation with the ``h2d_bytes`` of the host-resident
+        operands it hands to JAX) and ``repro.result``.  Without a
+        ``tracer`` they go to the JAX profiler only, at no sync and no
+        clock read.
+
         Telemetry (both default off; the untimed path is the exact
         legacy loop, bit-identical results):
 
-          * ``tracer`` -- a :class:`repro.obs.Tracer`.  The solve emits
-            ``solve > data_prep / calibrate / outer_iter > step /
-            observe`` spans, and phase attribution (``repro.obs.
-            phases``) synthesizes ``local_solve`` and ``comm/<name>``
-            child spans inside every measured step -- one per collective
-            the solver's CommSchedule declares, sized by the program's
-            exact bytes-on-wire;
+          * ``tracer`` -- a :class:`repro.obs.Tracer` keeping the same
+            spans as events (Chrome trace / JSONL) as well;
           * ``registry`` -- a :class:`repro.obs.Registry`.  Per-iter
             metrics (``solver/objective``, ``solver/step_s``, phase
             histograms, cumulative ``solver/comm_bytes``, per-collective
             ``compress/ef_norm/*`` when error feedback is active,
             ``async/ring_occupancy`` under staleness) land in it, keyed
-            by ``{solver=..., engine=...}`` labels.
+            by ``{solver=..., engine=...}`` labels.  The local / comm
+            split of each step comes from a calibration of the program
+            against its collective-free twin (``repro.obs.phases``, a
+            ``repro.calibrate`` span), made only for a registry.
 
         Either one switches the driver to its timed path, which adds a
-        per-step device sync and per-iter ``step_s`` / ``local_s`` /
-        ``comm_s`` / ``host_s`` fields to the history; the iterates
-        themselves are unchanged.
+        per-step device sync and per-iter ``step_s`` / ``host_s`` fields
+        (and, with a registry, ``local_s`` / ``comm_s``) to the history;
+        the iterates themselves are unchanged.
         """
-        from repro.obs import as_tracer, calibrate_phases
+        from repro.obs import calibrate_phases
         from repro.obs.phases import bench_codecs
-        tr = as_tracer(tracer)
+        from repro.obs.trace import PROFILER_TRACER, as_tracer
+        tr = as_tracer(tracer, PROFILER_TRACER)
         reg = registry
         timed = tr.enabled or reg is not None
         loss = get_loss(loss_name)
         cfg = cfg if cfg is not None else self.config_cls()
         policy = self.active_policy
         labels = {"solver": self.name, "engine": self.engine}
-        with tr.span("solve", loss=loss_name, **labels):
-            with tr.span("data_prep"):
+        with tr.span("repro.solve", **labels):
+            with tr.span("repro.prep"):
                 prog = self.program(loss_name, X, y, P=P, Q=Q, cfg=cfg,
                                     mesh=mesh, warm_start=warm_start,
-                                    row_gate=row_gate)
+                                    row_gate=row_gate, tracer=tr)
             split = None
-            if timed:
-                with tr.span("calibrate"):
+            if reg is not None:
+                with tr.span("repro.calibrate"):
                     split = calibrate_phases(prog)
-                if policy is not None:
-                    codec_s = bench_codecs(policy,
-                                           prog.comm_bytes or {})
-                    for cname, secs in codec_s.items():
-                        if reg is not None:
-                            reg.gauge(f"compress/codec_s/{cname}",
-                                      **labels).set(secs)
-                    if codec_s:
-                        tr.instant("codec_bench", **codec_s)
+                    codec_s = (bench_codecs(policy, prog.comm_bytes or {})
+                               if policy is not None else {})
+                for cname, secs in codec_s.items():
+                    reg.gauge(f"compress/codec_s/{cname}",
+                              **labels).set(secs)
+                if codec_s:
+                    tr.instant("codec_bench", **codec_s)
             lam = cfg.lam
             history: List[Dict[str, float]] = []
             need_obs = (record_history or callback is not None
@@ -552,7 +584,7 @@ class Solver:
             t0 = time.perf_counter()
             last_phase: Dict[str, float] = {}
 
-            def on_step(t, t_begin, step_s):
+            def on_step(t, step_s):
                 last_phase.clear()
                 last_phase["step_s"] = step_s
                 if split is not None:
@@ -562,11 +594,6 @@ class Solver:
                     for key in ("comm_exposed_s", "comm_hidden_s"):
                         if key in att:
                             last_phase[key] = att[key]
-                    tr.record("local_solve", t_begin, att["local_s"], iter=t)
-                    off = t_begin + att["local_s"]
-                    for name, secs in att["collectives"].items():
-                        tr.record(f"comm/{name}", off, secs, iter=t)
-                        off += secs
                 if reg is not None:
                     reg.histogram("solver/step_s", **labels).observe(step_s)
                     if split is not None:
@@ -588,7 +615,9 @@ class Solver:
                 th0 = time.perf_counter()
                 w = prog.w_of(state)
                 alpha = prog.alpha_of(state) if prog.alpha_of else None
-                f = float(loss.objective(X, y, w, lam))
+                with tr.span("repro.observe.primal", iter=t,
+                             h2d_bytes=host_nbytes(X, y, w)):
+                    f = float(loss.objective(X, y, w, lam))
                 entry = {"iter": t + iter_offset,
                          "time_s": time.perf_counter() - t0 + time_offset,
                          "objective": f}
@@ -603,8 +632,10 @@ class Solver:
                     # declared collective launches once per step)
                     entry["comm_bytes"] = bytes_offset + bytes_per_step * t
                 if alpha is not None:
-                    entry["duality_gap"] = float(
-                        f - loss.dual_objective(X, y, alpha, lam))
+                    with tr.span("repro.observe.dual", iter=t,
+                                 h2d_bytes=host_nbytes(X, y, alpha)):
+                        entry["duality_gap"] = float(
+                            f - loss.dual_objective(X, y, alpha, lam))
                 if f_star is not None:
                     entry["rel_opt"] = float(rel_opt(f, f_star))
                 if timed:
@@ -656,22 +687,22 @@ class Solver:
                 return stop
 
             state, iters, stopped = drive(
-                prog, cfg.outer_iters, observe,
-                tracer=tr if tr.enabled else None,
+                prog, cfg.outer_iters, observe, tracer=tr,
                 on_step=on_step if timed else None,
                 monitor=monitor)
-            res = SolveResult(
-                w=prog.w_of(state),
-                alpha=prog.alpha_of(state) if prog.alpha_of else None,
-                history=history, iters=iters,
-                converged=stopped and not advanced[0],
-                solver=self.name, engine=self.engine,
-                local_backend=self.local_backend,
-                block_format=self.block_format,
-                staleness=self.staleness,
-                compression=policy.spec if policy is not None else None,
-                topology=self.topology_spec,
-                comm_bytes=prog.comm_bytes)
+            with tr.span("repro.result"):
+                res = SolveResult(
+                    w=prog.w_of(state),
+                    alpha=prog.alpha_of(state) if prog.alpha_of else None,
+                    history=history, iters=iters,
+                    converged=stopped and not advanced[0],
+                    solver=self.name, engine=self.engine,
+                    local_backend=self.local_backend,
+                    block_format=self.block_format,
+                    staleness=self.staleness,
+                    compression=policy.spec if policy is not None else None,
+                    topology=self.topology_spec,
+                    comm_bytes=prog.comm_bytes)
             return res, advanced[0]
 
 

@@ -1,4 +1,4 @@
-"""Small shared helpers for the shard_map engines."""
+"""Small shared helpers for the engines and the solver driver."""
 from __future__ import annotations
 
 import jax
@@ -44,3 +44,19 @@ def axes_index(axis):
     for a in axes[1:]:
         idx = idx * jax.lax.psum(1, a) + jax.lax.axis_index(a)
     return idx
+
+
+def host_nbytes(*operands) -> int:
+    """Bytes of the host-resident operands among ``operands``: what
+    handing them to a JAX computation sends to the device.  Device
+    arrays and None send nothing; an operand with an ``h2d_bytes()``
+    method (:class:`~repro.data.sparse.CSRMatrix`) says what it sends."""
+    total = 0
+    for x in operands:
+        if x is None or isinstance(x, jax.Array):
+            continue
+        if hasattr(x, "h2d_bytes"):
+            total += x.h2d_bytes()
+        else:
+            total += int(getattr(x, "nbytes", 0))
+    return total

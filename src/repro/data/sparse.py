@@ -94,6 +94,13 @@ class CSRMatrix:
             object.__setattr__(self, "_coo_cache", cached)  # frozen dataclass
         return cached
 
+    def h2d_bytes(self) -> int:
+        """Bytes the next matvec sends to the device: the COO triplet
+        until :meth:`_device_coo` has cached it, then none."""
+        if getattr(self, "_coo_cache", None) is not None:
+            return 0
+        return self.data.nbytes + self.indices.nbytes + 8 * self.nnz
+
     def toarray(self) -> np.ndarray:
         """Densify (small instances / reference solves only)."""
         n, m = self.shape

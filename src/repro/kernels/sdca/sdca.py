@@ -132,6 +132,7 @@ def sdca_epoch_pallas(x, y, mask, alpha0, w0, idx, *, lam, n, Q,
     )
     dalpha, w_fin = pl.pallas_call(
         kern,
+        name="sdca_dense",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(y2.shape, jnp.float32),

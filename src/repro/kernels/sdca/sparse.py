@@ -129,6 +129,7 @@ def sdca_epoch_sparse_pallas(cols, vals, y, mask, alpha0, w0, idx, *, lam, n,
     )
     dalpha, w_fin = pl.pallas_call(
         kern,
+        name="sdca_sparse",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(y2.shape, jnp.float32),

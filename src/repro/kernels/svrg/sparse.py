@@ -127,6 +127,7 @@ def svrg_inner_sparse_pallas(cols, vals, y, mask, z_anchor, w_anchor, mu_sub,
     )
     w = pl.pallas_call(
         kern,
+        name="svrg_sparse",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(wa2.shape, jnp.float32),
         interpret=resolve_interpret(interpret),

@@ -94,6 +94,7 @@ def svrg_inner_pallas(x_sub, y, mask, z_anchor, w_anchor, mu_sub, idx, *,
     )
     w = pl.pallas_call(
         kern,
+        name="svrg_dense",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, m_sub), jnp.float32),
         interpret=resolve_interpret(interpret),

@@ -12,16 +12,17 @@ Modules:
                    clock, thread-safe, near-zero overhead when disabled
                    (``NULL_TRACER``); exports Chrome-trace JSON
                    (chrome://tracing / Perfetto) and a JSONL event log;
-                   optional ``jax.profiler`` TraceAnnotation
-                   pass-through so spans appear in device profiles
+                   every span is also a ``jax.profiler`` TraceAnnotation,
+                   so it appears in device profiles, and
+                   ``PROFILER_TRACER`` writes only those annotations
   * ``metrics`` -- :class:`Registry` of labelled counters / gauges /
                    histograms with one ``snapshot()`` schema shared by
                    every BENCH emitter; absorbs the legacy percentile
                    helpers
-  * ``phases``  -- per-phase wall-clock attribution: calibrates the
-                   local-solve vs communication split of an
-                   :class:`~repro.core.engines.EngineProgram` (via its
-                   collective-free ``local_step``) and prices each
+  * ``phases``  -- per-phase wall-clock attribution for a registry:
+                   calibrates the local-solve vs communication split of
+                   an :class:`~repro.core.engines.EngineProgram` (via
+                   its collective-free ``local_step``) and prices each
                    named collective's share; per-codec encode/decode
                    microbench
   * ``serve``   -- :class:`RequestMetrics`: the serving engine's
@@ -61,13 +62,15 @@ from .metrics import Counter, Gauge, Histogram, Registry, percentiles
 from .phases import PhaseSplit, bench_codecs, calibrate_phases
 from .recorder import BUNDLE_SCHEMA, FlightRecorder, load_bundle
 from .serve import RequestMetrics
-from .trace import NULL_TRACER, NullTracer, Tracer, as_tracer
+from .trace import (NULL_TRACER, PROFILER_TRACER, NullTracer, ProfilerTracer,
+                    Tracer, as_tracer)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "percentiles",
     "PhaseSplit", "bench_codecs", "calibrate_phases",
     "RequestMetrics",
-    "NULL_TRACER", "NullTracer", "Tracer", "as_tracer",
+    "NULL_TRACER", "NullTracer", "PROFILER_TRACER", "ProfilerTracer",
+    "Tracer", "as_tracer",
     "BUNDLE_SCHEMA", "FlightRecorder", "load_bundle",
     "OK", "WARN", "CRIT", "HealthEvent", "HealthRule", "HealthMonitor",
     "rule_divergence", "rule_gap_stall", "rule_staleness",

@@ -1,4 +1,5 @@
-"""Per-phase wall-clock attribution for engine programs.
+"""Per-phase wall-clock attribution for engine programs, for a solve
+given a metrics registry.
 
 An outer iteration's wall-clock decomposes into
 
@@ -13,10 +14,8 @@ SAME cell program with every collective executed cell-locally
 (:class:`~repro.core.comm.LocalComm`: psum/pmean return the cell's own
 contribution, allgather broadcasts it) -- which costs the local math
 without the reductions.  ``comm_s = step_s - local_step_s`` is then the
-communication share, and it is split across the named collectives
-proportionally to their exact bytes-on-wire (from the program's
-``comm_bytes`` accounting), which is the attribution model a bandwidth
--bound interconnect obeys.
+communication share.  Per-collective device times come from a profile
+of the step, where each collective is its own op.
 
 :func:`calibrate_phases` measures the split once per program (a few
 timed steps of each variant); :meth:`PhaseSplit.attribute` then prices
@@ -59,8 +58,6 @@ class PhaseSplit:
 
     #: fraction of a step spent in the cell-local solve (0..1)
     local_frac: float
-    #: each named collective's share of the comm fraction (sums to 1)
-    comm_shares: Dict[str, float]
     #: calibration measurements, for provenance
     step_s: float
     local_s: float
@@ -74,14 +71,11 @@ class PhaseSplit:
         """Split one measured step duration into phases::
 
             {"local_s": ..., "comm_s": ...,
-             ["comm_hidden_s": ..., "comm_exposed_s": ...,]
-             "collectives": {name: seconds}}
+             ["comm_hidden_s": ..., "comm_exposed_s": ...]}
         """
         local = step_s * self.local_frac
         comm = max(step_s - local, 0.0)
-        out = {"local_s": local, "comm_s": comm,
-               "collectives": {name: comm * share
-                               for name, share in self.comm_shares.items()}}
+        out = {"local_s": local, "comm_s": comm}
         if self.overlap and self.staleness > 0:
             from repro.core.comm_model import overlap_split
             out.update(overlap_split(comm, local, self.staleness))
@@ -92,8 +86,8 @@ def calibrate_phases(prog, *, reps: int = 3) -> Optional[PhaseSplit]:
     """Measure a program's local/comm split (see module docstring).
 
     Returns None when the program carries no ``local_step`` (legacy
-    programs built outside the generic executors) -- callers then emit
-    only the undivided ``step`` span.  Warmup compiles both variants;
+    programs built outside the generic executors) -- callers then
+    record only the undivided ``step_s``.  Warmup compiles both variants;
     the calibration steps are pure (engine state is functional), so a
     calibrated solve returns bit-identical iterates.
     """
@@ -120,19 +114,7 @@ def calibrate_phases(prog, *, reps: int = 3) -> Optional[PhaseSplit]:
     jax.block_until_ready(local_step(1, state))
     local_s = _timeit(lambda: local_step(1, state), reps)
     local_frac = min(local_s / step_s, 1.0) if step_s > 0 else 1.0
-
-    acct = getattr(prog, "comm_bytes", None) or {}
-    coll = acct.get("collectives", {})
-    total_bytes = sum(c["bytes_per_step"] for c in coll.values())
-    if coll and total_bytes > 0:
-        shares = {name: c["bytes_per_step"] / total_bytes
-                  for name, c in coll.items()}
-    elif coll:                      # all-zero payloads: split evenly
-        shares = {name: 1.0 / len(coll) for name in coll}
-    else:
-        shares = {}
-    return PhaseSplit(local_frac=local_frac, comm_shares=shares,
-                      step_s=step_s, local_s=local_s,
+    return PhaseSplit(local_frac=local_frac, step_s=step_s, local_s=local_s,
                       staleness=int(getattr(prog, "staleness", 0)),
                       overlap=bool(getattr(prog, "overlap", False)))
 

@@ -43,8 +43,8 @@ from .trace import Tracer
 #: bundle schema identifier (bump on incompatible layout changes)
 BUNDLE_SCHEMA = "repro.obs.flight_recorder/1"
 
-#: default ring capacity -- at one outer_iter + step + observe + a few
-#: comm spans per iteration this holds on the order of the last ~500
+#: default ring capacity -- at repro.iter + step + observe + its two
+#: evaluations per iteration this holds on the order of the last ~800
 #: iterations of a solve, in a few MB of host memory
 DEFAULT_CAPACITY = 4096
 
@@ -72,17 +72,14 @@ class FlightRecorder(Tracer):
         ``snapshot()`` is embedded in every bundle.
       meta: JSON-able dict merged into every bundle's ``meta`` block
         (the services stamp their config here).
-      jax_annotations: see :class:`Tracer`.
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
-                 clock=time.perf_counter, registry=None, meta=None,
-                 jax_annotations: bool = False):
+                 clock=time.perf_counter, registry=None, meta=None):
         if capacity < 1:
             raise ValueError(f"recorder capacity must be >= 1, "
                              f"got {capacity}")
-        super().__init__(clock=clock, enabled=True,
-                         jax_annotations=jax_annotations)
+        super().__init__(clock=clock, enabled=True)
         self.capacity = int(capacity)
         # the ring: deque(maxlen=) drops the oldest entry on append-at-
         # capacity in O(1); every Tracer export/query path copies it

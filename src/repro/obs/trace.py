@@ -1,32 +1,36 @@
 """Structured tracing: nestable spans, Chrome-trace / JSONL exporters.
 
 A :class:`Tracer` records *spans* -- named wall-clock intervals that
-nest (outer_iter > step > comm/dalpha ...) -- plus *instant* events.
+nest (repro.iter > repro.step ...) -- plus *instant* events.
 Design constraints, in order:
 
   1. **near-zero overhead when disabled**: the module-level
      :data:`NULL_TRACER` hands out one shared no-op span object, so an
      instrumented hot loop costs a method call and an identity check
      per span when tracing is off;
-  2. **injectable clock** for deterministic tests (``clock=`` takes any
+  2. **one clock with the device**: every live span of an enabled
+     tracer is also a ``jax.profiler.TraceAnnotation`` carrying the
+     span's arguments, so the same names and counters show up in a
+     device profile captured with ``jax.profiler.trace``, on the clock
+     of the device's ops.  :data:`PROFILER_TRACER` writes those
+     annotations and nothing else (no event list, no clock read): the
+     solver driver's default, costing about a microsecond a span while
+     no profiler runs;
+  3. **injectable clock** for deterministic tests (``clock=`` takes any
      ``() -> float`` in seconds);
-  3. **thread-safe**: span stacks are per-thread (serving runs the
+  4. **thread-safe**: span stacks are per-thread (serving runs the
      engine loop on one thread and callbacks elsewhere), the event list
-     is lock-protected;
-  4. **post-measured spans**: phase attribution times a jitted step and
-     then *synthesizes* child spans inside the measured interval
-     (:meth:`Tracer.record`), since nothing can be timed inside an XLA
-     computation from the host.
+     is lock-protected.
+
+A counter known only once a span's work is done (the ELL width of a
+partition) is added with ``span.set_metadata(**counters)``, which the
+annotation and the exported event both carry.  :meth:`Tracer.record`
+adds an already-measured span.
 
 Exports: :meth:`Tracer.to_chrome_trace` produces the Trace Event Format
 consumed by ``chrome://tracing`` and https://ui.perfetto.dev (complete
 "X" events, microsecond timestamps); :meth:`Tracer.write_jsonl` writes
 one JSON object per event for ad-hoc analysis.
-
-Optional ``jax_annotations=True`` additionally enters a
-``jax.profiler.TraceAnnotation`` for every live span so the same names
-show up inside real device profiles captured with
-``jax.profiler.trace``.
 """
 from __future__ import annotations
 
@@ -36,12 +40,10 @@ import time
 from typing import Any, Dict, List, Optional
 
 
-def _jax_annotation(name: str):
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:               # jax absent or profiler API moved
-        return None
+try:
+    from jax.profiler import TraceAnnotation
+except ImportError:                 # jax absent: spans stay host-only
+    TraceAnnotation = None
 
 
 class _Span:
@@ -58,13 +60,18 @@ class _Span:
 
     def __enter__(self):
         tr = self._tracer
-        if tr.jax_annotations:
-            self._ann = _jax_annotation(self.name)
-            if self._ann is not None:
-                self._ann.__enter__()
+        if TraceAnnotation is not None:
+            self._ann = TraceAnnotation(self.name, **(self.args or {}))
+            self._ann.__enter__()
         tr._stack().append(self.name)
         self._t0 = tr.clock()
         return self
+
+    def set_metadata(self, **counters):
+        """Add arguments to the live span (and to its annotation)."""
+        self.args = {**(self.args or {}), **counters}
+        if self._ann is not None:
+            self._ann.set_metadata(**counters)
 
     def __exit__(self, exc_type, exc, tb):
         tr = self._tracer
@@ -86,6 +93,9 @@ class _NullSpan:
     def __enter__(self):
         return self
 
+    def set_metadata(self, **counters):
+        pass
+
     def __exit__(self, exc_type, exc, tb):
         return False
 
@@ -99,13 +109,13 @@ class Tracer:
     Events are dicts ``{name, ts, dur, depth, tid, args}`` with ``ts``
     (seconds since the tracer's epoch -- its construction time under the
     injected clock) and ``dur`` in seconds; instants have ``dur=None``.
+    ``enabled`` says whether the tracer keeps events (and so reads its
+    clock); the solver driver times its steps only for one that does.
     """
 
-    def __init__(self, clock=time.perf_counter, enabled: bool = True,
-                 jax_annotations: bool = False):
+    def __init__(self, clock=time.perf_counter, enabled: bool = True):
         self.clock = clock
         self.enabled = enabled
-        self.jax_annotations = jax_annotations
         self.events: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -120,9 +130,8 @@ class Tracer:
         return _Span(self, name, args or None)
 
     def record(self, name: str, t0: float, dur: float, **args):
-        """Add an already-measured span (``t0`` in this tracer's clock).
-        Used to synthesize attribution spans inside a timed interval --
-        e.g. per-collective comm spans inside a jitted step."""
+        """Add an already-measured span (``t0`` in this tracer's clock);
+        it exists only in this tracer's events, not in a profile."""
         if not self.enabled:
             return
         self._push_event(name, t0, dur, len(self._stack()), args or None)
@@ -219,10 +228,27 @@ class NullTracer(Tracer):
         pass
 
 
+class ProfilerTracer(NullTracer):
+    """Writes each span into the JAX profiler's trace and nothing else:
+    :meth:`span` is a ``jax.profiler.TraceAnnotation`` with the span's
+    arguments as its stats.  It keeps no events and reads no clock
+    (``enabled`` is False), so code driven by it never blocks to time
+    anything; with no profiler running a span costs about a
+    microsecond."""
+
+    def span(self, name: str, **args):
+        if TraceAnnotation is None:
+            return _NULL_SPAN
+        return TraceAnnotation(name, **args)
+
+
 #: the shared disabled tracer -- default for every instrumented code path
 NULL_TRACER = NullTracer()
+#: the shared profiler-only tracer -- the solver driver's default
+PROFILER_TRACER = ProfilerTracer()
 
 
-def as_tracer(tracer) -> Tracer:
-    """Normalize an optional tracer argument: None -> NULL_TRACER."""
-    return NULL_TRACER if tracer is None else tracer
+def as_tracer(tracer, default: Tracer = NULL_TRACER) -> Tracer:
+    """Normalize an optional tracer argument: None -> ``default``
+    (NULL_TRACER unless given)."""
+    return default if tracer is None else tracer

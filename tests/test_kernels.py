@@ -54,6 +54,44 @@ def test_svrg_kernel(n_p, m_sub, L, loss):
                                rtol=1e-5, atol=1e-5)
 
 
+def _solver_kernel_calls():
+    """Each solver kernel at a tiny size: (its name, fn, args)."""
+    from repro.kernels.sdca import sdca_epoch_sparse_pallas
+    from repro.kernels.svrg import svrg_inner_sparse_pallas
+    n_p, m_q, k, steps = 16, 8, 8, 4
+    f32 = lambda *shape: jnp.ones(shape, jnp.float32)  # noqa: E731
+    cols = jnp.zeros((n_p, k), jnp.int32)
+    idx = jnp.arange(steps, dtype=jnp.int32)
+    return {
+        "sdca_dense": (lambda *a: sdca_epoch_pallas(*a, lam=0.2, n=64, Q=2),
+                       (f32(n_p, m_q), f32(n_p), f32(n_p), f32(n_p),
+                        f32(m_q), idx)),
+        "sdca_sparse": (lambda *a: sdca_epoch_sparse_pallas(
+            *a, lam=0.2, n=64, Q=2),
+            (cols, f32(n_p, k), f32(n_p), f32(n_p), f32(n_p), f32(m_q),
+             idx)),
+        "svrg_dense": (lambda *a: svrg_inner_pallas(*a, lam=0.1, eta=0.03),
+                       (f32(n_p, m_q), f32(n_p), f32(n_p), f32(n_p),
+                        f32(m_q), f32(m_q), idx)),
+        "svrg_sparse": (lambda *a: svrg_inner_sparse_pallas(
+            *a, lam=0.1, eta=0.03),
+            (cols, f32(n_p, k), f32(n_p), f32(n_p), f32(n_p), f32(m_q),
+             f32(m_q), idx)),
+    }
+
+
+@pytest.mark.parametrize("name", ["sdca_dense", "sdca_sparse",
+                                  "svrg_dense", "svrg_sparse"])
+def test_solver_kernel_name_in_lowered_program(name):
+    """Each solver kernel carries its own name into the program it is
+    lowered into (the op names of its HLO), so a profile can find it."""
+    fn, args = _solver_kernel_calls()[name]
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    assert f"/{name}/pallas_call" in text
+    others = {"sdca_dense", "sdca_sparse", "svrg_dense", "svrg_sparse"}
+    assert not any(f"/{o}/" in text for o in others - {name})
+
+
 @pytest.mark.parametrize("B,S,H,KV,D", [(2, 128, 4, 2, 32), (1, 256, 2, 2, 64),
                                         (2, 64, 8, 1, 16)])
 @pytest.mark.parametrize("window", [None, 48])

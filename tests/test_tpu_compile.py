@@ -6,7 +6,9 @@ simulated-engine step) at the paper's sizes with its arguments placed on
 one device of a described ``v5e:2x2`` host, compiles it, and checks that
 the program holds the Pallas kernel (``tpu_custom_call``).  It catches
 what interpret mode cannot: block shapes the (8, 128) tiling refuses,
-and kernels that overflow on-chip memory.  Nothing runs, so nothing
+and kernels that overflow on-chip memory; and that each kernel carries
+its name into the compiled program (a lone kernel's op is
+``%sdca_dense.1 = ... custom-call``).  Nothing runs, so nothing
 here says anything about results or times.
 
 The topology is described inside a fixture, never while a module is
@@ -81,6 +83,7 @@ def test_sdca_dense_compiles(spec):
                          spec((n_p,)), spec((n_p,)), spec((m_q,)),
                          spec((n_p,), jnp.int32), spec(()))
     assert "tpu_custom_call" in text
+    assert "%sdca_dense" in text          # the kernel's op carries its name
 
 
 def test_svrg_dense_compiles(spec):
@@ -95,6 +98,7 @@ def test_svrg_dense_compiles(spec):
                          spec((n_p,)), spec((n_p,)), spec((m_sub,)),
                          spec((m_sub,)), spec((n_p,), jnp.int32), spec(()))
     assert "tpu_custom_call" in text
+    assert "%svrg_dense" in text          # the kernel's op carries its name
 
 
 def test_sdca_sparse_compiles(spec):
@@ -110,6 +114,7 @@ def test_sdca_sparse_compiles(spec):
                          spec((n_p,)), spec((n_p,)), spec((n_p,)),
                          spec((m_q,)), spec((n_p,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "%sdca_sparse" in text          # the kernel's op carries its name
 
 
 def test_svrg_sparse_compiles(spec):
@@ -127,6 +132,7 @@ def test_svrg_sparse_compiles(spec):
                          spec((n_p,), jnp.int32), spec(()),
                          spec((), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "%svrg_sparse" in text          # the kernel's op carries its name
 
 
 def test_d3ca_simulated_step_compiles(spec, monkeypatch):
@@ -148,6 +154,9 @@ def test_d3ca_simulated_step_compiles(spec, monkeypatch):
     state = (spec((P, n_p)), spec((Q, m_q)))
     text = step.lower(spec((), jnp.int32), data, state).compile().as_text()
     assert "tpu_custom_call" in text
+    # under the grid's vmap the op keeps a call's name; the kernel's
+    # name is in its op_name, vmap(vmap(sdca_dense))
+    assert "(sdca_dense)" in text
 
 
 def test_chip_smoke_refuses_cpu():
