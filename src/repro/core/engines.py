@@ -111,6 +111,12 @@ class EngineProgram:
     #: non-CPU backends): callers that re-step from a saved state must
     #: copy it first (see ``repro.obs.phases.calibrate_phases``)
     donated: bool = False
+    #: ``w -> F(w)`` and ``alpha -> D(alpha)`` of the global iterates
+    #: (what ``w_of`` / ``alpha_of`` give), evaluated on the training
+    #: blocks the program already holds on the device.  None: the solve
+    #: loop evaluates on the caller's X instead
+    primal_of: Optional[Callable[[Any], jnp.ndarray]] = None
+    dual_of: Optional[Callable[[Any], jnp.ndarray]] = None
 
 
 def drive(prog: EngineProgram, outer_iters: int, observe=None, *,

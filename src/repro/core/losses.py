@@ -37,16 +37,21 @@ class Loss:
 
     def objective(self, X, y, w, lam, mask=None, n=None):
         """Primal objective F(w); `mask` marks real (non-padded) rows."""
-        z = X @ w
+        n_eff = n if n is not None else (mask.sum() if mask is not None else X.shape[0])
+        return self.objective_of_margins(X @ w, y, w, lam, mask, n_eff)
+
+    def objective_of_margins(self, z, y, w, lam, mask, n):
+        """F(w) from the margins ``z = X w``: the mean of ``value`` over
+        the ``n`` real rows (``mask`` zeroes the padded ones) plus the
+        regularizer.  Elementwise in ``z``, ``y``, ``mask``: any shape."""
         vals = self.value(z, y)
         if mask is not None:
             vals = vals * mask
-        n_eff = n if n is not None else (mask.sum() if mask is not None else X.shape[0])
         # NOTE: the paper writes lam*||w||^2 in eq. (1) but its dual (2),
         # primal-dual map (3) and the SDCA closed form are all derived under
         # the standard (lam/2)*||w||^2 convention -- we use the latter
         # consistently (recorded in DESIGN.md §4).
-        return vals.sum() / n_eff + 0.5 * lam * jnp.sum(w * w)
+        return vals.sum() / n + 0.5 * lam * jnp.sum(w * w)
 
     def dual_objective(self, X, y, alpha, lam, mask=None, n=None):
         """Dual objective D(alpha) of eq. (2)."""
@@ -54,10 +59,16 @@ class Loss:
             alpha = alpha * mask
         n_eff = n if n is not None else (mask.sum() if mask is not None else X.shape[0])
         v = X.T @ alpha / (lam * n_eff)
+        return self.dual_objective_of_map(v, y, alpha, lam, mask, n_eff)
+
+    def dual_objective_of_map(self, v, y, alpha, lam, mask, n):
+        """D(alpha) from the primal-dual map ``v = X^T alpha / (lam n)``
+        of the masked ``alpha``.  Elementwise in ``y``, ``alpha``,
+        ``mask``: any shape."""
         conj_term = self.conj(alpha, y)
         if mask is not None:
             conj_term = conj_term * mask
-        return -conj_term.sum() / n_eff - lam / 2.0 * jnp.sum(v * v)
+        return -conj_term.sum() / n - lam / 2.0 * jnp.sum(v * v)
 
 
 # ----------------------------------------------------------------------------

@@ -8,6 +8,8 @@ The paper stores block ``x_[p,q]`` (observations p, features q) on worker
     cells on one device) and, row/column-sharded, by the shard_map execution
     where each device holds exactly one ``(n_p, m_q)`` block in HBM.
   * helpers to scatter/gather the global primal/dual vectors to/from blocks.
+  * the primal and dual objectives evaluated on the dense blocks, where
+    they already are (``block_objective`` / ``block_dual_objective``).
 
 Padding: rows are padded with x = 0 and mask = 0 so they contribute nothing
 to objectives/gradients; columns are padded with zero features (harmless --
@@ -17,7 +19,9 @@ data column is identically zero, and the regularizer only shrinks them).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -74,6 +78,49 @@ class DoublyPartitioned:
             self.P * self.n_p, self.Q * self.m_q
         )
         return Xp[: self.n, : self.m], self.y_blocks.reshape(-1)[: self.n]
+
+    # ---- objectives on the blocks -----------------------------------------
+    def objective(self, loss, w, lam):
+        """F(w) of the global ``(m,)`` iterate, on the device's blocks."""
+        return block_objective(loss, self.x_blocks, self.y_blocks,
+                               self.mask, w, lam=lam, n=self.n)
+
+    def dual_objective(self, loss, alpha, lam):
+        """D(alpha) of the global ``(n,)`` iterate, on the device's
+        blocks."""
+        return block_dual_objective(loss, self.x_blocks, self.y_blocks,
+                                    self.mask, alpha, lam=lam, n=self.n)
+
+
+# Both contractions are a multiply and a sum in float32: one pass over
+# the blocks, exact to float32 rounding whatever the backend's default
+# matmul precision.  The loss, lam and n are static, so every solve of
+# one shape and config reuses one compiled evaluator and sends the
+# device nothing.
+
+@partial(jax.jit, static_argnames=("loss", "lam", "n"))
+def block_objective(loss, x_blocks, y_blocks, mask, w, *, lam, n):
+    """Primal objective F(w) of :meth:`Loss.objective` from the dense
+    blocks ``(P, Q, n_p, m_q)``: z_p = sum_q X_pq w_q, the masked mean of
+    ``loss.value`` over the ``n`` real rows, plus (lam/2) ||w||^2.  ``w``
+    is the global ``(m,)`` iterate, zero-padded to the blocks here."""
+    Q, m_q = x_blocks.shape[1], x_blocks.shape[3]
+    w_b = jnp.pad(w, (0, Q * m_q - w.shape[0])).reshape(Q, m_q)
+    z = jnp.sum(x_blocks * w_b[None, :, None, :], axis=(1, 3))
+    return loss.objective_of_margins(z, y_blocks, w, lam, mask, n)
+
+
+@partial(jax.jit, static_argnames=("loss", "lam", "n"))
+def block_dual_objective(loss, x_blocks, y_blocks, mask, alpha, *, lam, n):
+    """Dual objective D(alpha) of :meth:`Loss.dual_objective` from the
+    dense blocks: v_q = sum_p X_pq^T alpha_p / (lam n), then
+    -sum conj / n - (lam/2) ||v||^2.  ``alpha`` is the global ``(n,)``
+    iterate, zero-padded to the ``(P, n_p)`` blocks here."""
+    P, n_p = y_blocks.shape
+    a_b = jnp.pad(alpha, (0, P * n_p - alpha.shape[0])).reshape(P, n_p)
+    a_b = a_b * mask
+    v = jnp.sum(x_blocks * a_b[:, None, :, None], axis=(0, 2)) / (lam * n)
+    return loss.dual_objective_of_map(v, y_blocks, a_b, lam, mask, n)
 
 
 def partition(X, y, P: int, Q: int, *,

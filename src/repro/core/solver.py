@@ -65,6 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Type
 
 from .admm import (ADMMConfig, admm_shard_map_program, admm_simulated_program,
@@ -275,7 +276,9 @@ class Solver:
             program-cache ``cache`` hit / miss / off) spans.
 
         Returns:
-          An :class:`EngineProgram` ready for :func:`engines.drive`.
+          An :class:`EngineProgram` ready for :func:`engines.drive`.  On
+          the simulated engine with dense blocks it carries ``primal_of``
+          / ``dual_of``, the objectives on those blocks.
 
         Raises:
           ValueError: on a missing grid spec, a mesh/grid mismatch, an
@@ -321,8 +324,18 @@ class Solver:
             cut = partition_sparse if sparse else partition
             data = cut(X, y, P, Q, m_multiple=P * Q, tracer=tr)
             with tr.span("repro.prep.bind", cache=hit):
-                return self._simulated_program(loss, data, cfg, w0, alpha0,
+                prog = self._simulated_program(loss, data, cfg, w0, alpha0,
                                                cache=cache, **gate_kw)
+                if sparse:
+                    # the caller's CSR X keeps its entries on the device;
+                    # the ELL blocks would pad them (more than twice as
+                    # many slots on the real-sim shape)
+                    return prog
+                return dataclasses.replace(
+                    prog,
+                    primal_of=partial(data.objective, loss, lam=cfg.lam),
+                    dual_of=(partial(data.dual_objective, loss, lam=cfg.lam)
+                             if prog.alpha_of else None))
         if mesh is None:
             if P is None or Q is None:
                 raise ValueError(f"engine={self.engine!r} needs a mesh "
@@ -522,7 +535,9 @@ class Solver:
         per outer iteration (:func:`~repro.core.engines.drive`'s
         ``repro.step`` and ``repro.observe``; the latter holds
         ``repro.observe.primal`` and ``repro.observe.dual``, one per
-        objective evaluation with the ``h2d_bytes`` of the host-resident
+        objective evaluation, with its ``operands``: ``"blocks"`` on the
+        program's device blocks (the simulated dense grid), ``"host"`` on
+        the caller's X, and the ``h2d_bytes`` of the host-resident
         operands it hands to JAX) and ``repro.result``.  Without a
         ``tracer`` they go to the JAX profiler only, at no sync and no
         clock read.
@@ -609,6 +624,16 @@ class Solver:
                         reg.counter("solver/comm_bytes", **labels).inc(
                             bytes_per_step)
 
+            # the objectives on the program's device blocks where it has
+            # them, else on the caller's (host or CSR) X
+            if prog.primal_of is not None:
+                operands, held = "blocks", ()
+                primal, dual = prog.primal_of, prog.dual_of
+            else:
+                operands, held = "host", (X, y)
+                primal = partial(loss.objective, X, y, lam=lam)
+                dual = partial(loss.dual_objective, X, y, lam=lam)
+
             def observe(t, state):
                 if not need_obs:
                     return False
@@ -616,8 +641,9 @@ class Solver:
                 w = prog.w_of(state)
                 alpha = prog.alpha_of(state) if prog.alpha_of else None
                 with tr.span("repro.observe.primal", iter=t,
-                             h2d_bytes=host_nbytes(X, y, w)):
-                    f = float(loss.objective(X, y, w, lam))
+                             operands=operands,
+                             h2d_bytes=host_nbytes(*held, w)):
+                    f = float(primal(w))
                 entry = {"iter": t + iter_offset,
                          "time_s": time.perf_counter() - t0 + time_offset,
                          "objective": f}
@@ -633,9 +659,9 @@ class Solver:
                     entry["comm_bytes"] = bytes_offset + bytes_per_step * t
                 if alpha is not None:
                     with tr.span("repro.observe.dual", iter=t,
-                                 h2d_bytes=host_nbytes(X, y, alpha)):
-                        entry["duality_gap"] = float(
-                            f - loss.dual_objective(X, y, alpha, lam))
+                                 operands=operands,
+                                 h2d_bytes=host_nbytes(*held, alpha)):
+                        entry["duality_gap"] = float(f - dual(alpha))
                 if f_star is not None:
                     entry["rel_opt"] = float(rel_opt(f, f_star))
                 if timed:

@@ -323,6 +323,32 @@ def test_trace_spans_cover_solve_wall_clock():
         for name in ("repro.observe.primal", "repro.observe.dual"):
             (span,) = [s for s in tr.spans(name) if s["args"]["iter"] == t]
             assert _inside(span, obs)
+            # the dense grid's evaluations read its device blocks
+            assert span["args"]["operands"] == "blocks"
+            assert span["args"]["h2d_bytes"] == 0
+
+
+@pytest.mark.obs
+def test_sparse_solve_evaluates_on_the_callers_csr():
+    """The sparse grid keeps the solve loop's evaluation on the caller's
+    CSR X: one primal and one dual span an iteration, on the host's
+    operands, sending the CSR once and the labels each time."""
+    from repro.core import D3CAConfig, get_solver
+    from repro.data.sparse import make_sparse_svm_csr
+
+    X, y = make_sparse_svm_csr(120, 40, density=0.2, seed=0)
+    cfg = D3CAConfig(lam=1e-1, outer_iters=3, local_steps=8)
+    tr = Tracer()
+    get_solver("d3ca")(engine="simulated", block_format="sparse").solve(
+        "hinge", X, y, P=2, Q=2, cfg=cfg, tracer=tr)
+    primal = tr.spans("repro.observe.primal")
+    dual = tr.spans("repro.observe.dual")
+    assert [s["args"]["iter"] for s in primal] == [1, 2, 3]
+    assert [s["args"]["iter"] for s in dual] == [1, 2, 3]
+    assert {s["args"]["operands"] for s in primal + dual} == {"host"}
+    assert [s["args"]["h2d_bytes"] for s in primal] == [
+        16 * X.nnz + 4 * 120, 4 * 120, 4 * 120]
+    assert [s["args"]["h2d_bytes"] for s in dual] == [4 * 120] * 3
 
 
 @pytest.mark.obs

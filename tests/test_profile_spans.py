@@ -108,14 +108,19 @@ def test_counters_equal_hand_counts(traced):
     sent = sum(s[3]["bytes"] for s in named(spans, "repro.prep.transfer"))
     primal = [s[3]["h2d_bytes"] for s in named(spans, "repro.observe.primal")]
     dual = [s[3]["h2d_bytes"] for s in named(spans, "repro.observe.dual")]
+    operands = {s[3]["operands"] for s in spans
+                if s[0] in ("repro.observe.primal", "repro.observe.dual")}
     n_p = -(-N // P)
     if fmt == "dense":
-        # X (N, M) and y (N,) in float32, sent once to be cut, and handed
-        # from the host to each evaluation
+        # X (N, M) and y (N,) in float32, sent once to be cut; each
+        # evaluation reads the blocks already on the device, and sends
+        # nothing
         assert sent == 4 * N * M + 4 * N
-        assert primal == dual == [4 * N * M + 4 * N] * CFG.outer_iters
+        assert primal == dual == [0] * CFG.outer_iters
+        assert operands == {"blocks"}
         assert "ell_k" not in named(spans, "repro.prep.partition")[0][3]
         return
+    assert operands == {"host"}
     # ELL: k is the most nonzeros of a row inside one feature block,
     # rounded up to 8; every slot of the P x Q x n_p x k grid that holds
     # no entry is padding

@@ -4,12 +4,13 @@ The TPU compiler is installed with jax, and it compiles for a chip that
 is described and not attached: each test lowers a kernel (or a whole
 simulated-engine step) at the paper's sizes with its arguments placed on
 one device of a described ``v5e:2x2`` host, compiles it, and checks that
-the program holds the Pallas kernel (``tpu_custom_call``).  It catches
-what interpret mode cannot: block shapes the (8, 128) tiling refuses,
-and kernels that overflow on-chip memory; and that each kernel carries
-its name into the compiled program (a lone kernel's op is
-``%sdca_dense.1 = ... custom-call``).  Nothing runs, so nothing
-here says anything about results or times.
+the program holds the Pallas kernel (``tpu_custom_call``); the solve
+loop's objectives on the dense blocks are checked for float32
+throughout.  It catches what interpret mode cannot: block shapes the
+(8, 128) tiling refuses, and kernels that overflow on-chip memory; and
+that each kernel carries its name into the compiled program (a lone
+kernel's op is ``%sdca_dense.1 = ... custom-call``).  Nothing runs, so
+nothing here says anything about results or times.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and
@@ -166,3 +167,23 @@ def test_chip_smoke_refuses_cpu():
     assert r.returncode != 0
     assert "no TPU" in r.stderr
     assert '"ok"' not in r.stdout
+
+
+@pytest.mark.parametrize("which", ["primal", "dual"])
+def test_block_objectives_compile_in_float32(spec, which):
+    """The solve loop's objectives on Part 1's dense blocks (4, 2, 2000,
+    3000) compile in float32 throughout (no bfloat16 product) and read
+    the blocks where they lie (no copy of them in the program)."""
+    from repro.core.losses import get_loss
+    from repro.core.partition import block_dual_objective, block_objective
+    P, Q, n_p, m_q = PART1["P"], PART1["Q"], PART1["n_p"], PART1["m_q"]
+    evaluate, size = ((block_objective, Q * m_q) if which == "primal"
+                      else (block_dual_objective, PART1["n"]))
+    text = evaluate.lower(get_loss("hinge"), spec((P, Q, n_p, m_q)),
+                          spec((P, n_p)), spec((P, n_p)), spec((size,)),
+                          lam=1e-2, n=PART1["n"]).compile().as_text()
+    assert "bf16" not in text
+    entry = text[text.index("ENTRY"):]
+    blocks = [line for line in entry.splitlines()
+              if "= f32[4,2,2000,3000]" in line]
+    assert len(blocks) == 1 and "parameter(0)" in blocks[0]
