@@ -171,7 +171,10 @@ class Comm:
             raise ValueError(f"reduction {name!r} executed twice in one "
                              "step; declare a second point instead")
         self._executed.add(name)
-        out = self._exec(point, value)
+        # names the collective's ops in their op_name metadata, and
+        # changes no op or number
+        with jax.named_scope(f"repro.comm.{name}"):
+            out = self._exec(point, value)
         if name not in self.wire_bytes:
             v = jnp.asarray(value)
             self.wire_bytes[name] = (math.prod(v.shape)

@@ -116,10 +116,11 @@ def d3ca_cell_program(loss: Loss, cfg: D3CAConfig, *, n: int, n_p: int,
         # step 6: alpha_[p,.] += (1/P) mean_q dalpha[p, q]
         a_new = a_b + comm("dalpha", dalpha) / Pn
         # step 9: w_[., q] = (1/(lam n)) sum_p alpha_[p,q]^T x_[p,q]
-        am = a_new * mask_b
-        contrib = (ell_scatter_add(m_q, cols_b, vals_b, am) if sparse
-                   else am @ x_b)
-        w_new = comm("w_contrib", contrib) / (lam_t * n_t)
+        with jax.named_scope("repro.d3ca.map"):
+            am = a_new * mask_b
+            contrib = (ell_scatter_add(m_q, cols_b, vals_b, am) if sparse
+                       else am @ x_b)
+            w_new = comm("w_contrib", contrib) / (lam_t * n_t)
         return a_new, w_new
 
     x_specs = ((("data", "model"), ("data", "model")) if sparse

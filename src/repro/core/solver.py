@@ -361,7 +361,8 @@ class Solver:
         prep = prepare_shard_map_sparse if sparse else prepare_shard_map
         sdata = prep(mesh, X, y, data_axis=data_axis,
                      model_axis=model_axis, m_multiple=Pn * Qn, tracer=tr)
-        with tr.span("repro.prep.bind", cache=hit):
+        mesh_shape = "x".join(str(s) for s in mesh.devices.shape)
+        with tr.span("repro.prep.bind", cache=hit, mesh=mesh_shape):
             return self._shard_map_program(loss, sdata, cfg, w0, alpha0,
                                            staleness=self.staleness,
                                            cache=cache, **gate_kw)

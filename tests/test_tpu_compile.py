@@ -36,6 +36,11 @@ PART1 = dict(n=8000, P=4, Q=2, n_p=2000, m_q=3000)
 #: seed 0; m_q = 20,958 / 2 for D3CA, and 20,960 / 2 for RADiSA, which
 #: pads the features to a multiple of P * Q
 REALSIM = dict(n=72309, n_p=18078, k=56, m_q=10479, m_q_radisa=10480)
+#: the paper's weak-scaling deployment on a 2x2 mesh (80,000 x 10,000 at
+#: 1%, ``chipbench/configs/weak_1pct_2x2_mesh.json``): one 40,000 x 5,000
+#: block a chip; k = 88 is the widest row's nonzeros in one feature block
+#: of the configuration's sparsity pattern, rounded up to 8
+WEAK = dict(n=80000, P=2, Q=2, n_p=40000, m_q=5000, k=88)
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +121,77 @@ def test_sdca_sparse_compiles(spec):
                          spec((m_q,)), spec((n_p,), jnp.int32))
     assert "tpu_custom_call" in text
     assert "%sdca_sparse" in text          # the kernel's op carries its name
+
+
+def test_sdca_sparse_compiles_at_weak_scaling_size(spec):
+    """The sparse kernel on one chip's block of the weak-scaling mesh: a
+    40,000-step coordinate order in scalar memory, 88-wide ELL rows."""
+    from repro.kernels.sdca import sdca_epoch_sparse_pallas
+    n_p, k, m_q = WEAK["n_p"], WEAK["k"], WEAK["m_q"]
+
+    def epoch(cols, vals, y, mask, a0, w0, idx):
+        return sdca_epoch_sparse_pallas(cols, vals, y, mask, a0, w0, idx,
+                                        lam=1.0, n=WEAK["n"], Q=WEAK["Q"],
+                                        interpret=False)
+
+    text = compiled_text(epoch, spec((n_p, k), jnp.int32), spec((n_p, k)),
+                         spec((n_p,)), spec((n_p,)), spec((n_p,)),
+                         spec((m_q,)), spec((n_p,), jnp.int32))
+    assert "%sdca_sparse" in text
+
+
+def test_d3ca_shard_map_step_compiles_on_four_chips(topo, monkeypatch):
+    """One whole outer step of the shard_map engine on the described 2x2
+    mesh at the weak-scaling size: the sparse kernel on every chip, the
+    two declared collectives as all-reduces over the chips, each op under
+    its named scope."""
+    import re
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    import repro.kernels
+    from repro.core.d3ca import D3CAConfig, d3ca_cell_program
+    from repro.core.engines import mesh_program
+    from repro.core.losses import get_loss
+    monkeypatch.setattr(repro.kernels, "default_interpret", lambda: False)
+    n, Pn, Qn = WEAK["n"], WEAK["P"], WEAK["Q"]
+    n_p, m_q, k = WEAK["n_p"], WEAK["m_q"], WEAK["k"]
+    mesh = Mesh(np.array(topo.devices).reshape(Pn, Qn), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+    def placed(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    cell = d3ca_cell_program(get_loss("hinge"), D3CAConfig(lam=1.0), n=n,
+                             n_p=n_p, m_q=m_q, sparse=True,
+                             local_backend="pallas")
+    data = (placed((2,), jnp.uint32),
+            placed((Pn * n_p, Qn * k), jnp.int32, "data", "model"),
+            placed((Pn * n_p, Qn * k), jnp.float32, "data", "model"),
+            placed((Pn * n_p,), jnp.float32, "data"),
+            placed((Pn * n_p,), jnp.float32, "data"))
+    state = (placed((Pn * n_p,), jnp.float32, "data"),
+             placed((Qn * m_q,), jnp.float32, "model"))
+    step, comm0, acct = mesh_program(cell, mesh, data, state)
+    assert acct["bytes_per_step"] == 4 * (n_p + m_q) * 4
+    text = step.lower(jax.ShapeDtypeStruct((), jnp.int32), data,
+                      (state, comm0)).compile().as_text()
+    assert "%sdca_sparse" in text
+    reduces = [line for line in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", line)]
+    scopes = sorted(re.search(r'op_name="[^"]*/(repro\.comm\.\w+)/',
+                              line).group(1) for line in reduces)
+    assert scopes == ["repro.comm.dalpha", "repro.comm.w_contrib"]
+    # dalpha over each row's two chips (model), w_contrib over each
+    # column's (data)
+    assert any("f32[40000]" in line and "{{0,1},{2,3}}" in line
+               for line in reduces)
+    assert any("f32[5000]" in line and "{{0,2},{1,3}}" in line
+               for line in reduces)
+    assert "repro.d3ca.map/repro.comm.w_contrib" in text
 
 
 def test_svrg_sparse_compiles(spec):
