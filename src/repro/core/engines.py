@@ -324,7 +324,7 @@ def prepare_shard_map_sparse(mesh, X, y, *, data_axis="data",
     (default the profiler-only tracer).
     """
     from repro.data.sparse import CSRMatrix, csr_from_dense
-    from .partition import _ceil_to as ceil_to, _ell_blocks, ell_counts
+    from .partition import _ceil_to as ceil_to, _ell_blocks
     tr = as_tracer(tracer, PROFILER_TRACER)
     Pn = axes_size(mesh, data_axis)
     Qn = axes_size(mesh, model_axis)
@@ -335,15 +335,14 @@ def prepare_shard_map_sparse(mesh, X, y, *, data_axis="data",
             X = csr_from_dense(np.asarray(X))
         n, m = X.shape
         m_pad = ceil_to(m, m_multiple or Qn)
-        cols, vals, y_blocks, mask_blocks = _ell_blocks(
+        cols, vals, y_blocks, mask_blocks, counters = _ell_blocks(
             X, y, Pn, Qn, m_pad, k_multiple)
-        span.set_metadata(**ell_counts(cols.shape, X.nnz))
-        _, _, n_p, k = cols.shape
-        # (P, Q, n_p, k) -> (P*n_p, Q*k): block (p, q) lands at the
+        span.set_metadata(**counters)
+        # (n_pad, Q, k) -> (P*n_p, Q*k), a view: block (p, q) lands at the
         # [p*n_p:(p+1)*n_p, q*k:(q+1)*k] tile, which the (data, model)
         # sharding assigns to device (p, q)
-        cols_g = cols.transpose(0, 2, 1, 3).reshape(Pn * n_p, Qn * k)
-        vals_g = vals.transpose(0, 2, 1, 3).reshape(Pn * n_p, Qn * k)
+        cols_g = cols.reshape(cols.shape[0], -1)
+        vals_g = vals.reshape(vals.shape[0], -1)
     daxes = as_axes(data_axis)
     put = _putter(mesh)
     with tr.span("repro.prep.transfer",

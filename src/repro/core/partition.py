@@ -195,46 +195,55 @@ def _ell_blocks(csr, y, P: int, Q: int, m_pad: int, k_multiple: int):
     (zero increments are inert), so the duplicate index-0 slots never
     change a result.
 
-    Returns numpy ``cols (P, Q, n_p, k) int32``, ``vals (..., k) f32``,
-    ``y_blocks (P, n_p)``, ``mask (P, n_p)``.
+    Each cell-row keeps its entries in CSR order.  Sorted by their pair
+    key ``row * Q + q``, the entries fill the ELL slots row-major: cell-row
+    ``(row, q)`` takes its first ``count`` slots.  CSR rows come in order,
+    so the key is already sorted when every row's columns ascend; only
+    input that is not (a libsvm file with unordered columns) pays a stable
+    permutation first.
+
+    Returns numpy ``cols (n_pad, Q, k) int32`` and ``vals (n_pad, Q, k)
+    f32``, row-major (``reshape(P, n_p, Q, k)`` gives cell ``(p, q)`` at
+    ``[p, :, q]``), ``y_blocks (P, n_p)``, ``mask (P, n_p)``, and the
+    span counters: ``ell_k``, the slots that hold an entry
+    (``useful_nnz``), the padding slots (``padded_slots``), and
+    ``ell_presorted`` (1 when the key came sorted, 0 when it was
+    permuted).
     """
-    import numpy as onp
     n = csr.shape[0]
     n_pad = _ceil_to(n, P)
     n_p, m_q = n_pad // P, m_pad // Q
 
+    key_t = np.int32 if n * Q < 2 ** 31 else np.int64
+    indices, data = csr.indices, csr.data
+    q_of = np.minimum(indices // m_q, Q - 1).astype(key_t)
+    row = np.repeat(np.arange(n, dtype=key_t), np.diff(csr.indptr))
+    pair = row * Q + q_of
     # per (row, q) nonzero count -> global k
-    q_of = onp.minimum(csr.indices // m_q, Q - 1)
-    row = csr.row_ids()
-    counts = onp.zeros((n, Q), dtype=onp.int64)
-    onp.add.at(counts, (row, q_of), 1)
+    counts = np.bincount(pair, minlength=n * Q)
     k_max = int(counts.max()) if counts.size else 0
     k = max(_ceil_to(max(k_max, 1), k_multiple), k_multiple)
 
-    cols = onp.zeros((P, Q, n_p, k), dtype=onp.int32)
-    vals = onp.zeros((P, Q, n_p, k), dtype=onp.float32)
-    # ELL slot of each entry = its rank within its (row, q) group (stable
-    # sort keeps the CSR entry order inside every group)
-    pair = row * Q + q_of
-    perm = onp.argsort(pair, kind="stable")
-    sp = pair[perm]
-    is_start = onp.r_[True, sp[1:] != sp[:-1]] if sp.size else \
-        onp.zeros((0,), dtype=bool)
-    run_id = onp.cumsum(is_start) - 1
-    run_starts = onp.flatnonzero(is_start)
-    ranks = onp.empty((csr.nnz,), dtype=onp.int64)
-    ranks[perm] = onp.arange(csr.nnz, dtype=onp.int64) - run_starts[run_id]
-    p_of = row // n_p
-    r_loc = row % n_p
-    c_loc = csr.indices - q_of * m_q
-    cols[p_of, q_of, r_loc, ranks] = c_loc.astype(onp.int32)
-    vals[p_of, q_of, r_loc, ranks] = csr.data.astype(onp.float32)
+    presorted = bool(np.all(pair[1:] >= pair[:-1]))
+    if not presorted:
+        perm = np.argsort(pair, kind="stable")
+        indices, data, q_of = indices[perm], data[perm], q_of[perm]
+    slots = np.zeros((n_pad * Q, k), dtype=bool)
+    slots[: n * Q] = np.arange(k) < counts[:, None]
+    cols = np.zeros((n_pad * Q, k), dtype=np.int32)
+    vals = np.zeros((n_pad * Q, k), dtype=np.float32)
+    cols[slots] = indices - q_of * m_q
+    vals[slots] = data
 
-    yp = onp.zeros((n_pad,), dtype=onp.float32)
-    yp[:n] = onp.asarray(y, dtype=onp.float32)
-    maskp = onp.zeros((n_pad,), dtype=onp.float32)
+    yp = np.zeros((n_pad,), dtype=np.float32)
+    yp[:n] = np.asarray(y, dtype=np.float32)
+    maskp = np.zeros((n_pad,), dtype=np.float32)
     maskp[:n] = 1.0
-    return cols, vals, yp.reshape(P, n_p), maskp.reshape(P, n_p)
+    counters = {"ell_k": k, "useful_nnz": csr.nnz,
+                "padded_slots": cols.size - csr.nnz,
+                "ell_presorted": int(presorted)}
+    return (cols.reshape(n_pad, Q, k), vals.reshape(n_pad, Q, k),
+            yp.reshape(P, n_p), maskp.reshape(P, n_p), counters)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -296,16 +305,6 @@ class SparseDoublyPartitioned:
         return X[: self.n, : self.m], y[: self.n]
 
 
-def ell_counts(shape, nnz: int) -> dict:
-    """The ELL counters of a grid of padded-ELL cells of ``shape`` (``(P,
-    Q, n_p, k)``) holding ``nnz`` entries: ``ell_k``, the slots that
-    hold an entry (``useful_nnz``) and the padding slots
-    (``padded_slots``)."""
-    slots = int(np.prod(shape))
-    return {"ell_k": int(shape[-1]), "useful_nnz": int(nnz),
-            "padded_slots": slots - int(nnz)}
-
-
 def partition_sparse(X, y, P: int, Q: int, *, m_multiple: int | None = None,
                      k_multiple: int = 8,
                      tracer=None) -> SparseDoublyPartitioned:
@@ -316,9 +315,9 @@ def partition_sparse(X, y, P: int, Q: int, *, m_multiple: int | None = None,
     rule matches ``partition(..., m_multiple=...)`` exactly, so sparse
     and dense runs see the same logical blocks.
 
-    The host's ELL build is a ``repro.prep.partition`` span carrying
-    :func:`ell_counts`, sending the cells a ``repro.prep.transfer`` span,
-    in ``tracer`` (default the profiler-only tracer).
+    The host's ELL build is a ``repro.prep.partition`` span carrying the
+    ELL counters, sending the cells a ``repro.prep.transfer`` span, in
+    ``tracer`` (default the profiler-only tracer).
     """
     from repro.data.sparse import CSRMatrix, csr_from_dense
     tr = as_tracer(tracer, PROFILER_TRACER)
@@ -329,9 +328,15 @@ def partition_sparse(X, y, P: int, Q: int, *, m_multiple: int | None = None,
             X = csr_from_dense(np.asarray(X))
         n, m = X.shape
         m_pad = _ceil_to(m, m_multiple or Q)
-        cols, vals, y_blocks, mask = _ell_blocks(X, y, P, Q, m_pad,
-                                                 k_multiple)
-        span.set_metadata(**ell_counts(cols.shape, X.nnz))
+        cols, vals, y_blocks, mask, counters = _ell_blocks(
+            X, y, P, Q, m_pad, k_multiple)
+        span.set_metadata(**counters)
+        n_p, k = y_blocks.shape[1], counters["ell_k"]
+        # (n_pad, Q, k) -> (P, Q, n_p, k), copied here and not in the put
+        cols = np.ascontiguousarray(
+            cols.reshape(P, n_p, Q, k).transpose(0, 2, 1, 3))
+        vals = np.ascontiguousarray(
+            vals.reshape(P, n_p, Q, k).transpose(0, 2, 1, 3))
     with tr.span("repro.prep.transfer",
                  bytes=host_nbytes(cols, vals, y_blocks, mask)):
         return SparseDoublyPartitioned(

@@ -123,7 +123,8 @@ def test_counters_equal_hand_counts(traced):
     assert operands == {"host"}
     # ELL: k is the most nonzeros of a row inside one feature block,
     # rounded up to 8; every slot of the P x Q x n_p x k grid that holds
-    # no entry is padding
+    # no entry is padding; the generator's rows have ascending columns,
+    # so the build takes them as they come
     m_q = -(-M // (P * Q)) * (P * Q) // Q
     rows = np.repeat(np.arange(N), np.diff(X.indptr))
     per_row_block = np.zeros((N, Q), int)
@@ -131,7 +132,8 @@ def test_counters_equal_hand_counts(traced):
     k = -(-per_row_block.max() // 8) * 8
     (cut,) = named(spans, "repro.prep.partition")
     assert cut[3] == {"ell_k": k, "useful_nnz": X.nnz,
-                      "padded_slots": P * Q * n_p * k - X.nnz}
+                      "padded_slots": P * Q * n_p * k - X.nnz,
+                      "ell_presorted": 1}
     # cols (int32) and vals (float32) of every cell, labels and mask
     assert sent == 2 * 4 * P * Q * n_p * k + 2 * 4 * P * n_p
     # the CSR triplet (float32 values, int32 columns, int64 rows) goes
