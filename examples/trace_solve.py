@@ -1,6 +1,6 @@
-"""Worked observability example: trace a D3CA solve, attribute its
-wall-clock to local-solve / communication / host phases, and export a
-Chrome-trace you can open in https://ui.perfetto.dev.
+"""Worked observability example: trace a D3CA solve, time its steps and
+its host bookkeeping, and export a Chrome-trace you can open in
+https://ui.perfetto.dev.
 
     PYTHONPATH=src python examples/trace_solve.py [--out trace.json]
 
@@ -11,10 +11,13 @@ What it shows:
     with its primal and dual evaluations) -- the same spans, with their
     counters, that a ``jax.profiler`` trace of the solve shows;
   * a ``Registry`` collecting the same run as counters / gauges /
-    histograms -- the one snapshot schema the BENCH emitters embed;
-  * the per-iteration ``step_s`` / ``local_s`` / ``comm_s`` / ``host_s``
-    fields that telemetry adds to ``SolveResult.history`` (the local /
-    comm split comes from the registry's phase calibration).
+    histograms;
+  * the per-iteration ``step_s`` / ``host_s`` fields that telemetry adds
+    to ``SolveResult.history``.
+
+Where a step's device time goes (the local kernel, the collectives) is
+read from a device profile of the solve (``jax.profiler``): the
+``repro.*`` spans and the ``repro.comm.<name>`` scopes mark it there.
 """
 import argparse
 import json
@@ -45,12 +48,10 @@ def main():
     res = solver.solve("hinge", X, y, P=2, Q=2, cfg=cfg,
                        tracer=tracer, registry=reg)
 
-    # 1. the per-phase fields telemetry added to the solve history
-    print("per-iteration phase attribution:")
+    # 1. the timing fields telemetry added to the solve history
+    print("per-iteration step and host time:")
     for h in res.history:
         print(f"  t={h['iter']:3d}  step {h['step_s'] * 1e3:7.3f} ms"
-              f"  = local {h['local_s'] * 1e3:7.3f}"
-              f"  + comm {h['comm_s'] * 1e3:7.3f}"
               f"  (obs host {h['host_s'] * 1e3:7.3f} ms)"
               f"   f={h['objective']:.6f}")
 
@@ -58,14 +59,14 @@ def main():
     solve_s = tracer.total("repro.solve")
     print(f"\nspan totals over {solve_s * 1e3:.1f} ms of solve:")
     for name in ("repro.prep", "repro.prep.partition", "repro.prep.transfer",
-                 "repro.prep.bind", "repro.calibrate", "repro.iter",
+                 "repro.prep.bind", "repro.iter",
                  "repro.step", "repro.observe", "repro.observe.primal",
                  "repro.observe.dual", "repro.result"):
         t = tracer.total(name)
         print(f"  {name:<22s} {t * 1e3:8.2f} ms  "
               f"({100 * t / solve_s:5.1f}%)")
 
-    # 3. the registry snapshot -- the same schema BENCH emitters embed
+    # 3. the registry snapshot
     snap = reg.snapshot()
     print("\nregistry snapshot (counters + a few gauges):")
     print(json.dumps({"counters": snap["counters"],

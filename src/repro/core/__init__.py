@@ -2,8 +2,7 @@
 from .admm import (ADMMConfig, admm_distributed,
                    admm_setup_simulated, admm_simulated)
 from .comm import Comm, CommSchedule, OverlapComm, StaleComm, SyncComm
-from .comm_model import (LinkModel, Topology, as_topology, fit_link,
-                         overlap_split, predict_comm_s)
+from .comm_model import Topology, as_topology
 from .compress import (CompressedComm, CompressionPolicy,
                        CompressionSchedule, as_compression, as_policy,
                        available_codecs, get_codec, wire_accounting)
@@ -26,8 +25,7 @@ __all__ = [
     "ADMMConfig", "admm_distributed", "admm_setup_simulated",
     "admm_simulated",
     "Comm", "CommSchedule", "OverlapComm", "StaleComm", "SyncComm",
-    "LinkModel", "Topology", "as_topology", "fit_link", "overlap_split",
-    "predict_comm_s",
+    "Topology", "as_topology",
     "CompressedComm", "CompressionPolicy", "CompressionSchedule",
     "as_compression", "as_policy", "available_codecs",
     "get_codec", "wire_accounting",

@@ -292,16 +292,15 @@ class SyncComm(Comm):
 
 
 class LocalComm(Comm):
-    """Collective-free executor for per-phase wall-clock attribution.
+    """Collective-free executor: the program with its exchanges left out.
 
     Every declared point is executed CELL-LOCALLY: psum/pmean return the
     cell's own contribution unchanged and allgather broadcasts it to the
     gathered shape -- same aval as the real reduction, zero bytes on the
     wire.  The numerics are wrong on purpose; a program built with this
-    executor (``EngineProgram.local_step``) is only ever *timed*, never
-    consumed: the difference between stepping the real program and
-    stepping this one isolates the communication cost
-    (:func:`repro.obs.phases.calibrate_phases`).
+    executor (``EngineProgram.local_step``) is what the benchmark's
+    ``exchange_left_out`` fault plants, so that its correctness check
+    is shown to catch a solver that skips its collectives.
     """
 
     def _exec(self, point: Collective, value):

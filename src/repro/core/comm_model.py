@@ -1,134 +1,20 @@
-"""Alpha-beta wire-time model for the declared collectives.
+"""Two-level topology spec and its exact wire accounting.
 
-The CommSchedule (Engine API v2) names every cross-cell reduction and
-``wire_accounting`` (PR 5) already reports *exact bytes per step*; this
-module turns those bytes into **predicted seconds** on a modelled
-interconnect, so the fig benchmarks can report predicted-vs-measured
-wall-clock per codec x tau x topology instead of just counting bytes.
-
-Model
------
-A link is ``(alpha, beta)``: per-message latency in seconds and
-per-byte inverse bandwidth in s/byte.  For an allreduce of ``n`` bytes
-over ``k`` participants:
-
-  * ring:  ``T = 2 (k - 1) alpha + 2 (k - 1)/k * n * beta``
-           (reduce-scatter + all-gather, the classic 2(k-1)/k factor --
-           bandwidth-optimal, latency grows linearly in k);
-  * tree:  ``T = 2 ceil(log2 k) (alpha + n beta)``
-           (recursive halving/doubling counted as log-depth full-vector
-           hops -- latency-optimal, pays the full vector per hop).
-
-For an allgather of ``n`` bytes contributed per participant:
-
-  * ring:  ``T = (k - 1) (alpha + n beta)``
-  * tree:  ``T = ceil(log2 k) alpha + (k - 1) n beta``
-
-``pmean`` costs the same wire time as ``psum`` (the division is local).
-
-Topology
---------
 ``Topology`` describes a two-level machine: ``pods`` groups along one
-logical axis (default ``"data"``), a fat intra-pod link and a thin
-inter-pod link, and an optional cross-pod codec.  A collective over the
-pod-split axis is executed hierarchically (full-precision reduce
-within the pod, codec-compressed across pods -- exactly what the
-hierarchical executors in :mod:`repro.core.comm` do), and its predicted
-time is the sum of the two stages.  Collectives over other axes ride
-the intra-pod link.
-
-Calibration
------------
-``fit_link`` least-squares fits ``(alpha, beta)`` from measured
-per-step ``comm_s`` samples (each sample: a schedule's accounting dict
-plus a measured time), clamping both at >= 0.  The fig benchmarks fit
-on their own sweep and report per-cell predicted seconds + relative
-error, which is how "predictions within 15% of measured" is checked.
-
-Overlap
--------
-``overlap_split`` applies the PR 6 phase attribution to the overlap
-engine: with ``tau`` steps of local work available to hide the wire,
-``hidden = min(comm_s, tau * local_s)`` and the *exposed* remainder is
-what lands on the critical path.
+logical axis (default ``"data"``) and an optional cross-pod codec.  A
+collective over the pod-split axis is executed hierarchically
+(full-precision reduce within the pod, codec-compressed across pods --
+what the hierarchical executors in :mod:`repro.core.comm` do), and
+:func:`hierarchical_accounting` splits its exact bytes per step into
+the two tiers.  How long the exchanges take is read from a device
+profile (the ``repro.comm.<name>`` scopes), not modelled here.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional
 
-__all__ = [
-    "LinkModel", "Topology", "INTRA_POD_LINK", "INTER_POD_LINK",
-    "collective_time", "predict_comm_s", "fit_link", "overlap_split",
-    "hierarchical_accounting",
-]
-
-
-@dataclasses.dataclass(frozen=True)
-class LinkModel:
-    """One interconnect link: ``alpha_s`` per-message latency and
-    ``beta_s_per_byte`` inverse bandwidth."""
-
-    alpha_s: float
-    beta_s_per_byte: float
-    name: str = "link"
-
-    def __post_init__(self):
-        if self.alpha_s < 0 or self.beta_s_per_byte < 0:
-            raise ValueError("LinkModel parameters must be >= 0")
-
-    @property
-    def bandwidth_gbps(self) -> float:
-        """Bidirectional bandwidth implied by beta, in GB/s."""
-        if self.beta_s_per_byte == 0:
-            return math.inf
-        return 1.0 / self.beta_s_per_byte / 1e9
-
-
-# Defaults roughly shaped like a TPU/GPU pod: a fat intra-pod ICI/NVLink
-# link and a thin inter-pod DCN link.  These are *priors* -- the fig
-# benchmarks re-fit alpha/beta from their own measured comm_s.
-INTRA_POD_LINK = LinkModel(1e-6, 1.0 / 300e9, name="intra_pod")
-INTER_POD_LINK = LinkModel(10e-6, 1.0 / 25e9, name="inter_pod")
-
-
-def _allreduce_time(nbytes: float, k: int, link: LinkModel,
-                    algo: str) -> float:
-    if k <= 1 or nbytes <= 0:
-        return 0.0
-    a, b = link.alpha_s, link.beta_s_per_byte
-    if algo == "ring":
-        return 2 * (k - 1) * a + 2 * (k - 1) / k * nbytes * b
-    if algo == "tree":
-        h = math.ceil(math.log2(k))
-        return 2 * h * (a + nbytes * b)
-    raise ValueError(f"unknown collective algorithm {algo!r} "
-                     "(expected 'ring' or 'tree')")
-
-
-def _allgather_time(nbytes: float, k: int, link: LinkModel,
-                    algo: str) -> float:
-    if k <= 1 or nbytes <= 0:
-        return 0.0
-    a, b = link.alpha_s, link.beta_s_per_byte
-    if algo == "ring":
-        return (k - 1) * (a + nbytes * b)
-    if algo == "tree":
-        return math.ceil(math.log2(k)) * a + (k - 1) * nbytes * b
-    raise ValueError(f"unknown collective algorithm {algo!r} "
-                     "(expected 'ring' or 'tree')")
-
-
-def collective_time(op: str, nbytes: float, k: int, link: LinkModel,
-                    algo: str = "ring") -> float:
-    """Predicted seconds for one ``op`` of ``nbytes`` (per participant)
-    over ``k`` participants on ``link``."""
-    if op in ("psum", "pmean"):
-        return _allreduce_time(nbytes, k, link, algo)
-    if op == "allgather":
-        return _allgather_time(nbytes, k, link, algo)
-    raise ValueError(f"unknown collective op {op!r}")
+__all__ = ["Topology", "as_topology", "hierarchical_accounting"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,17 +23,16 @@ class Topology:
 
     ``pods`` groups along logical ``axis`` (the leading mesh/vmap axis
     of the two-level split); ``codec`` names the cross-pod payload
-    codec ("identity" disables compression); ``algo`` selects the
-    wire-time formula.  ``pods == 1`` is the flat machine (the
-    executors then take the ordinary single-psum path).
+    codec ("identity" disables compression); ``algo`` ("ring" or
+    "tree") is the spec's third field, carried in ``spec`` only.
+    ``pods == 1`` is the flat machine (the executors then take the
+    ordinary single-psum path).
     """
 
     pods: int = 1
     codec: str = "identity"
     algo: str = "ring"
     axis: str = "data"
-    intra: LinkModel = INTRA_POD_LINK
-    inter: LinkModel = INTER_POD_LINK
 
     def __post_init__(self):
         if self.pods < 1:
@@ -252,129 +137,3 @@ def hierarchical_accounting(acct: dict, topology: Optional[Topology],
     out["intra_bytes_per_step"] = intra_total
     out["inter_bytes_per_step"] = inter_total
     return out
-
-
-def predict_comm_s(acct: dict, sizes: Dict[str, int], *,
-                   topology: Optional[Topology] = None,
-                   link: LinkModel = INTRA_POD_LINK,
-                   algo: str = "ring") -> dict:
-    """Predicted per-step communication seconds for a schedule.
-
-    ``acct`` is the ``wire_accounting`` dict attached to every
-    ``EngineProgram`` (``prog.comm_bytes``); ``sizes`` the logical axis
-    extents (``{"data": P, "model": Q}``).  Collectives are serial
-    within a step (each one is a data dependency of the next cell
-    phase), so the total is the sum over collectives.  With a
-    hierarchical topology the pod-split collectives cost
-    ``intra_stage + inter_stage``; independent reductions over the
-    *other* axis are modelled as perfectly parallel (disjoint links).
-
-    Returns ``{"collectives": {name: {...}}, "total_s": float}``.
-    """
-    out: dict = {"collectives": {}, "total_s": 0.0, "algo": algo}
-    for name, c in acct["collectives"].items():
-        axis = c.get("axis")
-        k = int(sizes.get(axis, 1))
-        per_cell = float(c["payload_bytes_per_cell"])
-        op = c.get("op", "psum")
-        entry: dict = {"axis": axis, "k": k, "bytes": per_cell}
-        if (topology is not None and topology.hierarchical()
-                and axis == topology.axis and k > 1):
-            k_in = k // topology.pods
-            intra = collective_time(op, per_cell, k_in, topology.intra,
-                                    topology.algo)
-            inter_bytes = _codec_nbytes(topology.codec, per_cell)
-            inter = collective_time(op, inter_bytes, topology.pods,
-                                    topology.inter, topology.algo)
-            entry.update(intra_s=intra, inter_s=inter,
-                         wire_s=intra + inter)
-        else:
-            tlink = link if topology is None else topology.intra
-            talgo = algo if topology is None else topology.algo
-            entry["wire_s"] = collective_time(op, per_cell, k, tlink, talgo)
-        out["collectives"][name] = entry
-        out["total_s"] += entry["wire_s"]
-    return out
-
-
-def _coeffs(acct: dict, sizes: Dict[str, int], algo: str) -> Tuple[float,
-                                                                   float]:
-    """(alpha, beta) coefficients of the linear model for one schedule:
-    predicted_s = A * alpha + B * beta on a single flat link."""
-    A = B = 0.0
-    for c in acct["collectives"].values():
-        k = int(sizes.get(c.get("axis"), 1))
-        n = float(c["payload_bytes_per_cell"])
-        if k <= 1 or n <= 0:
-            continue
-        op = c.get("op", "psum")
-        if op in ("psum", "pmean"):
-            if algo == "ring":
-                A += 2 * (k - 1)
-                B += 2 * (k - 1) / k * n
-            else:
-                A += 2 * math.ceil(math.log2(k))
-                B += 2 * math.ceil(math.log2(k)) * n
-        else:                                   # allgather
-            if algo == "ring":
-                A += (k - 1)
-                B += (k - 1) * n
-            else:
-                A += math.ceil(math.log2(k))
-                B += (k - 1) * n
-    return A, B
-
-
-def fit_link(samples: Sequence[Tuple[dict, Dict[str, int], float]], *,
-             algo: str = "ring", name: str = "fitted") -> LinkModel:
-    """Least-squares fit of ``(alpha, beta)`` from measured comm times.
-
-    Each sample is ``(acct, sizes, measured_comm_s)``.  Solves the 2x2
-    normal equations, clamps both parameters at >= 0 (re-solving the
-    1-parameter problem when one clamps), so the result is always a
-    valid :class:`LinkModel`.  With fewer than two samples (or a
-    singular system) it falls back to a pure-bandwidth fit.
-    """
-    rows: List[Tuple[float, float, float]] = []
-    for acct, sizes, t in samples:
-        A, B = _coeffs(acct, sizes, algo)
-        if A > 0 or B > 0:
-            rows.append((A, B, max(float(t), 0.0)))
-    if not rows:
-        return LinkModel(0.0, 0.0, name=name)
-    saa = sum(a * a for a, _, _ in rows)
-    sbb = sum(b * b for _, b, _ in rows)
-    sab = sum(a * b for a, b, _ in rows)
-    sat = sum(a * t for a, _, t in rows)
-    sbt = sum(b * t for _, b, t in rows)
-    det = saa * sbb - sab * sab
-    if det > 1e-30 * max(saa * sbb, 1e-30):
-        alpha = (sat * sbb - sbt * sab) / det
-        beta = (saa * sbt - sab * sat) / det
-    else:
-        alpha, beta = -1.0, -1.0                # force the clamp path
-    if alpha < 0 or beta < 0:
-        # Clamp + re-solve each 1-parameter problem, keep the better fit.
-        cand = []
-        if sbb > 0:
-            cand.append((0.0, max(sbt / sbb, 0.0)))
-        if saa > 0:
-            cand.append((max(sat / saa, 0.0), 0.0))
-        if not cand:
-            return LinkModel(0.0, 0.0, name=name)
-
-        def sse(ab):
-            a0, b0 = ab
-            return sum((a * a0 + b * b0 - t) ** 2 for a, b, t in rows)
-        alpha, beta = min(cand, key=sse)
-    return LinkModel(float(alpha), float(beta), name=name)
-
-
-def overlap_split(comm_s: float, local_s: float, tau: int) -> dict:
-    """Split measured ``comm_s`` into hidden vs exposed under the
-    overlap engine: tau steps of local solve are available to hide the
-    wire, so ``hidden = min(comm_s, tau * local_s)``.  tau = 0 (or the
-    sync/async engines) exposes everything."""
-    comm_s = max(float(comm_s), 0.0)
-    hidden = min(comm_s, max(int(tau), 0) * max(float(local_s), 0.0))
-    return {"comm_hidden_s": hidden, "comm_exposed_s": comm_s - hidden}

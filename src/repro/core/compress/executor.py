@@ -20,7 +20,7 @@ records the uncompressed size; this class overrides it with the codec's
 payload size).  :func:`wire_accounting` computes the same numbers
 statically from a schedule + payload avals -- that is what the engines
 attach to ``EngineProgram.comm_bytes`` and what surfaces in Solver
-history and the BENCH emitters.
+history and the metrics registry.
 """
 from __future__ import annotations
 
@@ -131,9 +131,7 @@ def wire_accounting(schedule: CommSchedule, payload_avals: dict,
         comp = codec.payload_nbytes(aval.shape, aval.dtype)
         per[point.name] = {
             "op": point.op, "axis": point.axis, "codec": codec.name,
-            # per-cell payload aval (what one cell hands to ``comm``);
-            # telemetry microbenchmarks each codec on it
-            # (repro.obs.phases.bench_codecs)
+            # per-cell payload aval (what one cell hands to ``comm``)
             "payload_shape": tuple(int(d) for d in aval.shape),
             "payload_dtype": str(jnp.dtype(aval.dtype).name),
             "payload_bytes_per_cell": int(comp),

@@ -9,7 +9,7 @@ engines here execute that single program three ways:
   * ``"simulated"``  -- :func:`grid_program`: the grid is the leading
     axes of blocked arrays and cells run under nested *named* ``vmap``
     on one device; the declared collectives become vmap-axis reductions
-    (correctness tests, paper-figure benchmarks);
+    (correctness tests, the one-chip benchmark cells);
   * ``"shard_map"``  -- :func:`mesh_program`: a (data=P, model=Q) device
     mesh where each device owns one (n_p, m_q) block in HBM and the
     collectives are mesh reductions, applied synchronously (the
@@ -88,8 +88,9 @@ class EngineProgram:
     comm_bytes: Optional[dict] = None
     #: same cell program with every collective executed cell-locally
     #: (:class:`~repro.core.comm.LocalComm`); jitted lazily, so it costs
-    #: nothing unless phase attribution times it.  Numerically wrong by
-    #: design -- timing only (see ``repro.obs.phases``)
+    #: nothing unless called.  Numerically wrong by design: it is the
+    #: program that leaves out its exchanges, which the benchmark's
+    #: ``exchange_left_out`` fault plants to show its check catches one
     local_step: Optional[Callable[[int, Any], Any]] = None
     #: state -> {collective: error-feedback residual array} when the
     #: compression policy carries stateful codecs (telemetry reads the
@@ -109,7 +110,7 @@ class EngineProgram:
     sync_of: Optional[Callable[[Any], Any]] = None
     #: True when ``step`` donates its state argument (overlap engine on
     #: non-CPU backends): callers that re-step from a saved state must
-    #: copy it first (see ``repro.obs.phases.calibrate_phases``)
+    #: copy it first
     donated: bool = False
     #: ``w -> F(w)`` and ``alpha -> D(alpha)`` of the global iterates
     #: (what ``w_of`` / ``alpha_of`` give), evaluated on the training
@@ -884,8 +885,8 @@ def mesh_program(cellprog: CellProgram, mesh, data, state0, *,
     are double-buffered reduction slots XLA can keep in flight across
     steps instead of defensively copying.  Donation is skipped on CPU
     (where it is a no-op) to keep host-side re-stepping from saved
-    states -- e.g. phase calibration -- unrestricted there; callers can
-    check ``EngineProgram.donated``."""
+    states unrestricted there; callers can check
+    ``EngineProgram.donated``."""
     topo = _norm_topology(topology)
     daxes = as_axes(data_axis)
     sizes = {"data": axes_size(mesh, data_axis),
@@ -942,12 +943,12 @@ def overlap_donates() -> bool:
 
 def mesh_local_step(cellprog: CellProgram, mesh, *, data_axis="data",
                     model_axis: str = "model"):
-    """Jitted collective-free twin of a mesh program's step, for the
-    differential phase attribution of :mod:`repro.obs.phases`:
+    """Jitted collective-free twin of a mesh program's step:
     ``local(t, data, state) -> state`` runs the same shard_map cell with
     every declared reduction executed cell-locally
     (:class:`~repro.core.comm.LocalComm`).  Numerically wrong on
-    purpose; only ever timed, never consumed."""
+    purpose: it is the program that leaves out its exchanges (the
+    benchmark's ``exchange_left_out`` fault)."""
     raw = mesh_step_fn(cellprog, mesh, data_axis=data_axis,
                        model_axis=model_axis, comm_local=True)
 

@@ -102,8 +102,7 @@ class SolveResult:
     history: List[Dict[str, float]]  # per-iter: iter, time_s, objective,
     #                                  [duality_gap], [rel_opt]; timed
     #                                  solves (tracer=/registry=) add
-    #                                  step_s, host_s; registry= adds
-    #                                  local_s, comm_s
+    #                                  step_s, host_s
     iters: int                      # outer iterations actually run
     converged: bool                 # True iff early stopping triggered
     solver: str
@@ -399,7 +398,7 @@ class Solver:
           tracer: a :class:`repro.obs.Tracer` (enables the timed path;
             default the profiler-only tracer, which adds no sync).
           registry: a :class:`repro.obs.Registry` for per-iter metrics
-            (enables the timed path and the phase calibration).
+            (enables the timed path).
           monitor: a :class:`repro.obs.HealthMonitor`; polled once per
             outer iteration (rules read the registry only -- iterates
             are untouched).
@@ -549,22 +548,19 @@ class Solver:
           * ``tracer`` -- a :class:`repro.obs.Tracer` keeping the same
             spans as events (Chrome trace / JSONL) as well;
           * ``registry`` -- a :class:`repro.obs.Registry`.  Per-iter
-            metrics (``solver/objective``, ``solver/step_s``, phase
-            histograms, cumulative ``solver/comm_bytes``, per-collective
-            ``compress/ef_norm/*`` when error feedback is active,
-            ``async/ring_occupancy`` under staleness) land in it, keyed
-            by ``{solver=..., engine=...}`` labels.  The local / comm
-            split of each step comes from a calibration of the program
-            against its collective-free twin (``repro.obs.phases``, a
-            ``repro.calibrate`` span), made only for a registry.
+            metrics (``solver/objective``, ``solver/step_s``,
+            ``solver/host_s``, cumulative ``solver/comm_bytes``,
+            per-collective ``compress/ef_norm/*`` when error feedback is
+            active, ``async/ring_occupancy`` under staleness) land in it,
+            keyed by ``{solver=..., engine=...}`` labels.
 
         Either one switches the driver to its timed path, which adds a
         per-step device sync and per-iter ``step_s`` / ``host_s`` fields
-        (and, with a registry, ``local_s`` / ``comm_s``) to the history;
-        the iterates themselves are unchanged.
+        to the history; the iterates themselves are unchanged.  Where a
+        step's device time goes (kernel, collectives) is read from the
+        profile, under the ``repro.*`` spans and ``repro.comm.<name>``
+        scopes.
         """
-        from repro.obs import calibrate_phases
-        from repro.obs.phases import bench_codecs
         from repro.obs.trace import PROFILER_TRACER, as_tracer
         tr = as_tracer(tracer, PROFILER_TRACER)
         reg = registry
@@ -578,17 +574,6 @@ class Solver:
                 prog = self.program(loss_name, X, y, P=P, Q=Q, cfg=cfg,
                                     mesh=mesh, warm_start=warm_start,
                                     row_gate=row_gate, tracer=tr)
-            split = None
-            if reg is not None:
-                with tr.span("repro.calibrate"):
-                    split = calibrate_phases(prog)
-                    codec_s = (bench_codecs(policy, prog.comm_bytes or {})
-                               if policy is not None else {})
-                for cname, secs in codec_s.items():
-                    reg.gauge(f"compress/codec_s/{cname}",
-                              **labels).set(secs)
-                if codec_s:
-                    tr.instant("codec_bench", **codec_s)
             lam = cfg.lam
             history: List[Dict[str, float]] = []
             need_obs = (record_history or callback is not None
@@ -598,29 +583,12 @@ class Solver:
             metric_vals: List[float] = []
             bytes_per_step = (prog.comm_bytes or {}).get("bytes_per_step")
             t0 = time.perf_counter()
-            last_phase: Dict[str, float] = {}
+            last_step: Dict[str, float] = {}
 
             def on_step(t, step_s):
-                last_phase.clear()
-                last_phase["step_s"] = step_s
-                if split is not None:
-                    att = split.attribute(step_s)
-                    last_phase["local_s"] = att["local_s"]
-                    last_phase["comm_s"] = att["comm_s"]
-                    for key in ("comm_exposed_s", "comm_hidden_s"):
-                        if key in att:
-                            last_phase[key] = att[key]
+                last_step["step_s"] = step_s
                 if reg is not None:
                     reg.histogram("solver/step_s", **labels).observe(step_s)
-                    if split is not None:
-                        reg.histogram("solver/local_s", **labels).observe(
-                            last_phase["local_s"])
-                        reg.histogram("solver/comm_s", **labels).observe(
-                            last_phase["comm_s"])
-                        if "comm_exposed_s" in last_phase:
-                            reg.histogram("solver/comm_exposed_s",
-                                          **labels).observe(
-                                last_phase["comm_exposed_s"])
                     if bytes_per_step is not None:
                         reg.counter("solver/comm_bytes", **labels).inc(
                             bytes_per_step)
@@ -653,7 +621,7 @@ class Solver:
                     entry["codec"] = policy.spec if policy is not None \
                         else None
                 if timed:
-                    entry.update(last_phase)
+                    entry.update(last_step)
                 if bytes_per_step is not None:
                     # cumulative bytes-on-wire after t outer steps (every
                     # declared collective launches once per step)

@@ -143,9 +143,9 @@ def build_parser():
                     help="trace the solve and write Chrome-trace JSON "
                          "here (open in chrome://tracing or "
                          "ui.perfetto.dev); spans cover data prep, every "
-                         "outer iteration, the cell-local solve and one "
-                         "span per declared collective.  OUT.jsonl is "
-                         "written next to it with the raw events")
+                         "outer iteration, its step and its objective "
+                         "evaluations.  OUT.jsonl is written next to it "
+                         "with the raw events")
     ap.add_argument("--metrics", action="store_true",
                     help="record solver metrics into a registry and "
                          "print its snapshot in the summary JSON")
@@ -277,22 +277,6 @@ def main(argv=None):
             line += f"  gap={h['duality_gap']:.3e}"
         if "rel_opt" in h:
             line += f"  rel_opt={h['rel_opt']:.4f}"
-        print(line)
-
-    phased = [h for h in res.history if "local_s" in h]
-    if phased:
-        tot = sum(h["step_s"] + h["host_s"] for h in phased)
-        loc = sum(h["local_s"] for h in phased)
-        com = sum(h["comm_s"] for h in phased)
-        hst = sum(h["host_s"] for h in phased)
-        line = (f"[optimize] phases: local {100 * loc / tot:.1f}% / "
-                f"comm {100 * com / tot:.1f}% / host "
-                f"{100 * hst / tot:.1f}% of {tot:.3f}s measured")
-        if any("comm_exposed_s" in h for h in phased):
-            exp = sum(h.get("comm_exposed_s", 0.0) for h in phased)
-            hid = sum(h.get("comm_hidden_s", 0.0) for h in phased)
-            line += (f" (comm exposed {100 * exp / tot:.1f}% / "
-                     f"hidden {100 * hid / tot:.1f}%)")
         print(line)
 
     summary = {

@@ -3,8 +3,8 @@
 Builds a synthetic mixed-length request trace and drives
 ``repro.serve.InferenceEngine`` (paged KV cache, prefill/decode
 interleave, per-request sampling).  The old static prefill+decode loop
-lives on in ``static_batch_generate`` as the benchmark baseline
-(benchmarks/serve_bench.py).
+lives on in ``static_batch_generate``, the baseline the tests compare
+the engine with.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --reduced \
         --requests 8 --prompt-len 8 --prompt-len-max 32 --gen 16 \
@@ -47,9 +47,8 @@ def static_batch_generate(model, params, requests, batch_size):
     slot decodes until the slowest request in its batch finishes.
 
     Returns {rid: generated tokens} -- the baseline continuous batching
-    is measured against (benchmarks/serve_bench.py).  The jitted
-    prefill/decode are cached on ``model`` so repeated calls (benchmark
-    warmup vs timed pass) hit the same compilation cache.
+    is compared with.  The jitted prefill/decode are cached on ``model``
+    so repeated calls hit the same compilation cache.
 
     Kept verbatim as the seed behaved, flaw included: in a batch of
     MIXED prompt lengths the shorter rows are right-padded and their

@@ -1,11 +1,11 @@
-"""repro.obs -- unified telemetry: tracing, metrics, phase attribution.
+"""repro.obs -- unified telemetry: tracing, metrics, health.
 
-The paper's central claim is about *scaling properties* -- where
-wall-clock goes as the P x Q grid grows -- so the repo needs one
-measurement substrate that attributes time to the local Pallas solve vs
-the declared collectives vs host bookkeeping, instead of four
-instrumentation dialects (solver ``history`` dicts, ``ServeMetrics``,
-``Comm.wire_bytes``, BENCH provenance stamps).
+One measurement substrate instead of four instrumentation dialects
+(solver ``history`` dicts, ``ServeMetrics``, ``Comm.wire_bytes``,
+provenance stamps).  Where a step's device time goes -- the local
+kernel, the declared collectives -- is read from a device profile, in
+which the solver's ``repro.*`` spans and ``repro.comm.<name>`` scopes
+mark it.
 
 Modules:
   * ``trace``   -- :class:`Tracer`: nestable spans with an injectable
@@ -17,14 +17,8 @@ Modules:
                    ``PROFILER_TRACER`` writes only those annotations
   * ``metrics`` -- :class:`Registry` of labelled counters / gauges /
                    histograms with one ``snapshot()`` schema shared by
-                   every BENCH emitter; absorbs the legacy percentile
+                   every exporter; absorbs the legacy percentile
                    helpers
-  * ``phases``  -- per-phase wall-clock attribution for a registry:
-                   calibrates the local-solve vs communication split of
-                   an :class:`~repro.core.engines.EngineProgram` (via
-                   its collective-free ``local_step``) and prices each
-                   named collective's share; per-codec encode/decode
-                   microbench
   * ``serve``   -- :class:`RequestMetrics`: the serving engine's
                    request-lifecycle bookkeeping (tok/s, TTFT, latency
                    percentiles) written through a Registry; the legacy
@@ -37,7 +31,7 @@ Modules:
                    :func:`load_bundle`)
   * ``health``  -- declarative :class:`HealthRule` catalog over the
                    registry (divergence, gap stall, staleness, queue
-                   shed, fleet starvation, exposed-comm share) and the
+                   shed, fleet starvation) and the
                    :class:`HealthMonitor` that evaluates them, records
                    verdicts as metrics, and edge-triggers recorder
                    dumps on CRIT
@@ -53,13 +47,12 @@ the observability layer sits below both and is threaded through them.
 """
 from .export import parse_prometheus_text, render_prometheus
 from .health import (CRIT, OK, WARN, HealthEvent, HealthMonitor, HealthRule,
-                     fleet_rules, online_rules, rule_comm_exposed,
-                     rule_divergence, rule_fleet_starvation, rule_gap_stall,
-                     rule_queue_shed, rule_staleness, rule_version_lag,
-                     serve_rules, solver_rules)
+                     fleet_rules, online_rules, rule_divergence,
+                     rule_fleet_starvation, rule_gap_stall, rule_queue_shed,
+                     rule_staleness, rule_version_lag, serve_rules,
+                     solver_rules)
 from .http import ObsServer
 from .metrics import Counter, Gauge, Histogram, Registry, percentiles
-from .phases import PhaseSplit, bench_codecs, calibrate_phases
 from .recorder import BUNDLE_SCHEMA, FlightRecorder, load_bundle
 from .serve import RequestMetrics
 from .trace import (NULL_TRACER, PROFILER_TRACER, NullTracer, ProfilerTracer,
@@ -67,7 +60,6 @@ from .trace import (NULL_TRACER, PROFILER_TRACER, NullTracer, ProfilerTracer,
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "percentiles",
-    "PhaseSplit", "bench_codecs", "calibrate_phases",
     "RequestMetrics",
     "NULL_TRACER", "NullTracer", "PROFILER_TRACER", "ProfilerTracer",
     "Tracer", "as_tracer",
@@ -75,7 +67,6 @@ __all__ = [
     "OK", "WARN", "CRIT", "HealthEvent", "HealthRule", "HealthMonitor",
     "rule_divergence", "rule_gap_stall", "rule_staleness",
     "rule_version_lag", "rule_queue_shed", "rule_fleet_starvation",
-    "rule_comm_exposed",
     "solver_rules", "online_rules", "serve_rules", "fleet_rules",
     "render_prometheus", "parse_prometheus_text",
     "ObsServer",

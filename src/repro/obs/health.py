@@ -15,13 +15,12 @@ in the serve engine costs a clock read.
 
 The catalog (:func:`rule_divergence`, :func:`rule_gap_stall`,
 :func:`rule_staleness`, :func:`rule_version_lag`,
-:func:`rule_queue_shed`, :func:`rule_fleet_starvation`,
-:func:`rule_comm_exposed`) covers the signals the algorithms already
-export: NaN / non-improving ``solver/objective``-``solver/rel_opt``
-(the D3CA dual ascent diverging), a stalled duality gap, the online
-service's staleness gauge and version lag, the admission queue's shed
-rate, starved fleet buckets, and the exposed-communication share of a
-step.  :func:`solver_rules` / :func:`online_rules` / :func:`serve_rules`
+:func:`rule_queue_shed`, :func:`rule_fleet_starvation`) covers the
+signals the algorithms already export: NaN / non-improving
+``solver/objective``-``solver/rel_opt`` (the D3CA dual ascent
+diverging), a stalled duality gap, the online service's staleness gauge
+and version lag, the admission queue's shed rate, and starved fleet
+buckets.  :func:`solver_rules` / :func:`online_rules` / :func:`serve_rules`
 / :func:`fleet_rules` bundle sensible defaults per service.
 """
 from __future__ import annotations
@@ -239,34 +238,6 @@ def rule_fleet_starvation(min_tenants: int = 2,
                       f"fleet bucket running under {min_tenants} tenants")
 
 
-def rule_comm_exposed(max_share: float = 0.5,
-                      comm: str = "solver/comm_exposed_s",
-                      comm_fallback: str = "solver/comm_s",
-                      step: str = "solver/step_s",
-                      name: str = "comm_exposed") -> HealthRule:
-    """WARN when the exposed-communication share of the mean outer step
-    exceeds ``max_share`` -- the wire is eating the critical path
-    (overlap cells report ``comm_exposed_s``; hidden comm is free)."""
-
-    def check(snap):
-        hists = snap.get("histograms", {})
-        steps = _series(hists, step)
-        comms = _series(hists, comm) or _series(hists, comm_fallback)
-        step_sum = sum(h["sum"] for h in steps.values())
-        comm_sum = sum(h["sum"] for h in comms.values())
-        if step_sum <= 0 or not comms:
-            return OK, "no phased step series yet", None
-        share = comm_sum / step_sum
-        if share > max_share:
-            return WARN, (f"exposed comm is {100 * share:.1f}% of step "
-                          f"(> {100 * max_share:.1f}%)"), share
-        return OK, f"exposed comm {100 * share:.1f}% of step", share
-
-    return check_rule(name, check,
-                      f"exposed comm share of a step above "
-                      f"{100 * max_share:.0f}%")
-
-
 def check_rule(name: str, check, description: str = "") -> HealthRule:
     """Tiny constructor shim so the factories above read declaratively."""
     return HealthRule(name=name, check=check, description=description)
@@ -276,12 +247,10 @@ def check_rule(name: str, check, description: str = "") -> HealthRule:
 # bundled defaults per service
 # ---------------------------------------------------------------------------
 
-def solver_rules(*, stall_window: int = 8,
-                 max_comm_share: float = 0.75) -> List[HealthRule]:
+def solver_rules(*, stall_window: int = 8) -> List[HealthRule]:
     """Rules for a batch/long solve driven through ``Solver.solve``."""
     return [rule_divergence(window=stall_window),
-            rule_gap_stall(window=stall_window),
-            rule_comm_exposed(max_share=max_comm_share)]
+            rule_gap_stall(window=stall_window)]
 
 
 def online_rules(*, max_staleness_s: float = 60.0, max_lag: float = 10_000,

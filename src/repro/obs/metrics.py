@@ -3,7 +3,7 @@
 One :class:`Registry` absorbs the repo's scattered bookkeeping dialects
 -- the serving engine's percentile counters, the solver driver's
 per-iteration history fields, the compressed-comm wire accounting --
-behind a single ``snapshot()`` schema every BENCH emitter can embed::
+behind a single ``snapshot()`` schema every exporter can embed::
 
     reg = Registry()
     reg.counter("serve/prefills").inc()
@@ -205,7 +205,7 @@ class Registry:
                          lambda: Histogram(qs, cap))
 
     def snapshot(self) -> dict:
-        """The one schema every BENCH emitter embeds: plain JSON-able
+        """The one schema every exporter embeds: plain JSON-able
         dicts keyed by ``name{label=value,...}``."""
         with self._lock:
             items = list(self._metrics.items())

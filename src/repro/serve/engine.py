@@ -350,7 +350,7 @@ class InferenceEngine:
         ``outputs`` and ``metrics`` accumulate across calls (requests
         may also be submit()ed before run); for per-batch numbers on a
         reused engine, swap in a fresh ``RequestMetrics`` first and
-        select outputs by rid -- benchmarks/serve_bench.py does this."""
+        select outputs by rid."""
         for r in requests:
             self.submit(r)
         while self.step():

@@ -1,79 +1,12 @@
-"""Alpha-beta wire-time model (core/comm_model.py) and the adaptive
-compression schedule: closed-form collective times, topology spec
-parsing, hierarchical byte accounting, link fitting, and the
-hidden-vs-exposed overlap split.  Pure host-side math -- no devices."""
-import math
-
+"""Topology spec and hierarchical byte accounting (core/comm_model.py),
+and the adaptive compression schedule.  Pure host-side math -- no
+devices."""
 import pytest
 
-from repro.core.comm_model import (INTER_POD_LINK, INTRA_POD_LINK, LinkModel,
-                                   Topology, as_topology, collective_time,
-                                   fit_link, hierarchical_accounting,
-                                   overlap_split, predict_comm_s)
+from repro.core.comm_model import (Topology, as_topology,
+                                   hierarchical_accounting)
 from repro.core.compress import (CompressionPolicy, CompressionSchedule,
                                  as_compression)
-
-
-# ---------------------------------------------------------------------------
-# link + closed-form collective times
-# ---------------------------------------------------------------------------
-
-def test_link_model_validation_and_bandwidth():
-    link = LinkModel(1e-6, 1.0 / 100e9)
-    assert link.bandwidth_gbps == pytest.approx(100.0)
-    assert LinkModel(0.0, 0.0).bandwidth_gbps == math.inf
-    with pytest.raises(ValueError, match=">= 0"):
-        LinkModel(-1e-6, 0.0)
-
-
-def test_allreduce_ring_formula():
-    # ring: 2(k-1) alpha + 2(k-1)/k n beta -- the classic factor
-    a, b, n, k = 2e-6, 1e-9, 4096.0, 8
-    link = LinkModel(a, b)
-    expect = 2 * (k - 1) * a + 2 * (k - 1) / k * n * b
-    assert collective_time("psum", n, k, link, "ring") == pytest.approx(
-        expect)
-    # pmean costs the same wire (division is local)
-    assert collective_time("pmean", n, k, link, "ring") == pytest.approx(
-        expect)
-
-
-def test_allreduce_tree_formula():
-    a, b, n, k = 2e-6, 1e-9, 4096.0, 6          # non-power-of-2: ceil(log2)
-    link = LinkModel(a, b)
-    h = math.ceil(math.log2(k))
-    assert collective_time("psum", n, k, link, "tree") == pytest.approx(
-        2 * h * (a + n * b))
-
-
-def test_allgather_formulas():
-    a, b, n, k = 2e-6, 1e-9, 1024.0, 4
-    link = LinkModel(a, b)
-    assert collective_time("allgather", n, k, link, "ring") == pytest.approx(
-        (k - 1) * (a + n * b))
-    assert collective_time("allgather", n, k, link, "tree") == pytest.approx(
-        math.ceil(math.log2(k)) * a + (k - 1) * n * b)
-
-
-def test_collective_time_degenerate_and_errors():
-    link = LinkModel(1e-6, 1e-9)
-    assert collective_time("psum", 1024.0, 1, link) == 0.0    # k=1: no wire
-    assert collective_time("psum", 0.0, 8, link) == 0.0
-    with pytest.raises(ValueError, match="algorithm"):
-        collective_time("psum", 64.0, 4, link, "butterfly")
-    with pytest.raises(ValueError, match="op"):
-        collective_time("reduce", 64.0, 4, link)
-
-
-def test_ring_beats_tree_on_bandwidth_tree_on_latency():
-    # the reason both algos exist: ring is bandwidth-optimal (big n),
-    # tree is latency-optimal (large k, small n)
-    fat = LinkModel(1e-6, 1e-9)
-    big, small, k = 1e8, 8.0, 64
-    assert (collective_time("psum", big, k, fat, "ring")
-            < collective_time("psum", big, k, fat, "tree"))
-    assert (collective_time("psum", small, k, fat, "tree")
-            < collective_time("psum", small, k, fat, "ring"))
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +41,7 @@ def test_as_topology():
 
 
 # ---------------------------------------------------------------------------
-# accounting -> predicted seconds
+# hierarchical byte accounting
 # ---------------------------------------------------------------------------
 
 def _acct(per_cell=4096, cells=8, op="psum", axis="data", name="g"):
@@ -118,27 +51,6 @@ def _acct(per_cell=4096, cells=8, op="psum", axis="data", name="g"):
                "bytes_per_step": per_cell * cells, "op": op, "axis": axis}},
         "bytes_per_step": per_cell * cells,
         "uncompressed_bytes_per_step": per_cell * cells}
-
-
-def test_predict_comm_s_flat():
-    acct = _acct(per_cell=4096, op="psum", axis="data")
-    link = LinkModel(1e-6, 1e-9)
-    pred = predict_comm_s(acct, {"data": 4, "model": 2}, link=link)
-    assert pred["total_s"] == pytest.approx(
-        collective_time("psum", 4096, 4, link, "ring"))
-    assert pred["collectives"]["g"]["k"] == 4
-
-
-def test_predict_comm_s_hierarchical_sums_stages():
-    topo = Topology(pods=2, codec="identity")
-    acct = _acct(per_cell=4096, axis="data")
-    pred = predict_comm_s(acct, {"data": 8, "model": 1}, topology=topo)
-    c = pred["collectives"]["g"]
-    intra = collective_time("psum", 4096, 4, topo.intra, "ring")
-    inter = collective_time("psum", 4096, 2, topo.inter, "ring")
-    assert c["intra_s"] == pytest.approx(intra)
-    assert c["inter_s"] == pytest.approx(inter)
-    assert pred["total_s"] == pytest.approx(intra + inter)
 
 
 def test_hierarchical_accounting_tiers():
@@ -166,58 +78,6 @@ def test_hierarchical_accounting_tiers():
     o = hierarchical_accounting(other, topo, {"data": 8, "model": 1})
     assert o["collectives"]["g"]["inter_bytes_per_step"] == 0.0
     assert o["collectives"]["g"]["bytes_per_step"] == 512 * 8
-
-
-# ---------------------------------------------------------------------------
-# link fitting
-# ---------------------------------------------------------------------------
-
-def test_fit_link_recovers_known_parameters():
-    true = LinkModel(3e-6, 2e-9)
-    samples = []
-    for per_cell, k in ((1024, 4), (8192, 4), (65536, 8), (256, 8)):
-        acct = _acct(per_cell=per_cell, cells=k, axis="data")
-        sizes = {"data": k, "model": 1}
-        t = predict_comm_s(acct, sizes, link=true)["total_s"]
-        samples.append((acct, sizes, t))
-    fit = fit_link(samples)
-    assert fit.alpha_s == pytest.approx(true.alpha_s, rel=1e-6)
-    assert fit.beta_s_per_byte == pytest.approx(true.beta_s_per_byte,
-                                                rel=1e-6)
-
-
-def test_fit_link_clamps_and_degenerates():
-    acct = _acct(per_cell=4096, cells=4, axis="data")
-    sizes = {"data": 4, "model": 1}
-    # a single sample: falls back to a 1-parameter fit, still >= 0
-    one = fit_link([(acct, sizes, 1e-3)])
-    assert one.alpha_s >= 0 and one.beta_s_per_byte >= 0
-    assert predict_comm_s(acct, sizes, link=one)["total_s"] > 0
-    # no usable samples -> the zero link, not an exception
-    empty = fit_link([])
-    assert (empty.alpha_s, empty.beta_s_per_byte) == (0.0, 0.0)
-    solo = fit_link([(_acct(per_cell=64, cells=1, axis="data"),
-                      {"data": 1, "model": 1}, 1e-3)])   # k=1: no wire
-    assert (solo.alpha_s, solo.beta_s_per_byte) == (0.0, 0.0)
-
-
-# ---------------------------------------------------------------------------
-# overlap split
-# ---------------------------------------------------------------------------
-
-def test_overlap_split():
-    # tau steps of local work hide up to tau * local_s of wire
-    s = overlap_split(comm_s=3.0, local_s=1.0, tau=2)
-    assert s == {"comm_hidden_s": 2.0, "comm_exposed_s": 1.0}
-    # everything hidden when the wire fits in the window
-    s = overlap_split(comm_s=1.5, local_s=1.0, tau=2)
-    assert s["comm_exposed_s"] == 0.0 and s["comm_hidden_s"] == 1.5
-    # tau = 0 exposes everything (the sync/async engines)
-    s = overlap_split(comm_s=3.0, local_s=1.0, tau=0)
-    assert s["comm_hidden_s"] == 0.0 and s["comm_exposed_s"] == 3.0
-    # negative inputs clamp instead of going nonsensical
-    s = overlap_split(comm_s=-1.0, local_s=1.0, tau=2)
-    assert s == {"comm_hidden_s": 0.0, "comm_exposed_s": 0.0}
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """The solver's spans in a JAX profile, on the CPU: a tiny D3CA solve,
 dense and sparse, under ``jax.profiler``; the span tree, its counters
 against hand counts from the shapes, and what a solve without a tracer
-does (no sync of its own, no events, no calibration, the same answer)."""
+does (no sync of its own, no events, the same answer)."""
 import tempfile
 from pathlib import Path
 
@@ -161,10 +161,6 @@ def count_syncs(monkeypatch):
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda x: calls.append(1) or real(x))
-
-    def no_calibration(prog):
-        raise AssertionError("a solve calibrated its phases")
-    monkeypatch.setattr("repro.obs.calibrate_phases", no_calibration)
     return calls
 
 
